@@ -121,16 +121,21 @@ def _absorb_scan(u0: jax.Array, touched0: jax.Array, sems: jax.Array,
 @partial(jax.jit, static_argnames=("cfg", "absorb"))
 def run_round(state: ClientState, table: CacheTable, sems: jax.Array,
               logits: jax.Array, cfg: CacheConfig,
-              absorb: AbsorptionConfig) -> RoundOutput:
+              absorb: AbsorptionConfig,
+              look: LookupResult | None = None) -> RoundOutput:
     """Process one round of F frames with a fixed allocated cache.
 
     ``sems``   — (F, L, d) pooled semantic taps (model forward already done —
                  the simulator owns the latency accounting via exit layers),
     ``logits`` — (F, C) full-model outputs (used on cache miss + absorption).
+    ``look``   — the Eq. (1)/(2) lookup of ``sems`` in ``table`` when the
+                 caller already ran it (the round engine looks up every
+                 client in one batched launch); ``None`` runs it here.
     """
     F = sems.shape[0]
     L = cfg.num_layers
-    look = lookup_all_layers(table, sems, cfg)
+    if look is None:
+        look = lookup_all_layers(table, sems, cfg)
 
     model_pred = jnp.argmax(logits, axis=-1).astype(jnp.int32)
     pred = jnp.where(look.hit, look.pred, model_pred)

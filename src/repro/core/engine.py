@@ -513,12 +513,13 @@ def _init_clients_batched(cfg: CacheConfig, num_clients: int) -> ClientState:
 
 
 @partial(jax.jit, static_argnames=("cfg", "absorb", "scfg", "cm",
-                                   "global_updates", "deadline"))
+                                   "global_updates", "deadline", "mesh"))
 def round_step(states: ClientState, tables: CacheTable, sems: jax.Array,
                logits: jax.Array, server: ServerState,
                *, cfg: CacheConfig, absorb: AbsorptionConfig,
                scfg: ServerConfig, cm: CostModel, global_updates: bool,
-               deadline: float | None, upload_mask: jax.Array | None = None):
+               deadline: float | None, upload_mask: jax.Array | None = None,
+               mesh=None):
     """One full round for all K clients as a single device computation:
     client round (vmapped) → uploads → Eq.-4/5 merges (``lax.scan``, client
     order preserved).
@@ -527,14 +528,21 @@ def round_step(states: ClientState, tables: CacheTable, sems: jax.Array,
     ``upload_mask`` — optional (K,) bool: clients whose Eq.-4/5 upload merges
     this round (the fault-injection harness masks dropped / delayed /
     quarantined uploads; ``None`` = everyone, the default path).
+    ``mesh`` — the mesh of a class-sharded ``server``: the merge runs per
+    class shard (:func:`repro.core.server.merge_round`), the lookup whole on
+    every device (:func:`repro.core.semantic_cache.lookup_all_layers`).
     Returns ``(new states, new server, per-frame metrics dict)`` — the
     metrics are (K, F) arrays (pred / hit / exit_layer / lat); nothing here
     forces a host sync.
     """
     states = reset_round(states)                     # elementwise, vmap-free
 
-    out = jax.vmap(lambda s, t, se, lo: run_round(s, t, se, lo, cfg, absorb))(
-        states, tables, sems, logits)
+    # Every client's lookup in one launch over the stacked tables; the rest
+    # of the client round is vmapped.
+    look = lookup_all_layers(tables, sems, cfg, mesh=mesh)._replace(acc=None)
+    out = jax.vmap(lambda s, t, se, lo, lk: run_round(s, t, se, lo, cfg,
+                                                      absorb, look=lk))(
+        states, tables, sems, logits, look)
 
     n_hot = tables.class_mask.sum(axis=1)                          # (K,)
     lat = jax.vmap(lambda e, lm, nh: frame_latency(cm, e, lm, nh))(
@@ -551,7 +559,7 @@ def round_step(states: ClientState, tables: CacheTable, sems: jax.Array,
         if upload_mask is not None:
             include = include & upload_mask
         uploads = make_upload(out.state)             # leading K axis on leaves
-        server = merge_round(server, uploads, include, scfg)
+        server = merge_round(server, uploads, include, scfg, mesh)
 
     return out.state, server, metrics
 
@@ -586,7 +594,7 @@ def bootstrap_server_from_taps(sim: SimulationConfig, sems: jax.Array,
         full = CacheTable(entries=lookup_entries,
                           class_mask=jnp.ones(sim.cache.num_classes, bool),
                           layer_mask=jnp.ones(sim.cache.num_layers, bool))
-        look = lookup_all_layers(full, sems, sim.cache)
+        look = lookup_all_layers(full, sems, sim.cache, mesh=mesh)
         first = np.bincount(np.asarray(look.exit_layer),
                             minlength=sim.cache.num_layers + 1)[:-1]
         r0 = np.cumsum(first) / max(len(shared_labels), 1)
@@ -1190,7 +1198,8 @@ class CocaCluster:
             states_in, tables, sems, logits, self._server,
             cfg=sim.cache, absorb=sim.absorb, scfg=sim.server, cm=self._cm,
             global_updates=sim.global_updates,
-            deadline=sim.straggler_deadline, upload_mask=mask)
+            deadline=sim.straggler_deadline, upload_mask=mask,
+            mesh=self._mesh)
         self._states = (new_states if all_active else
                         jax.tree_util.tree_map(
                             lambda full, new: full.at[idx].set(new),

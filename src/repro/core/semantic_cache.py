@@ -168,14 +168,23 @@ def pool_semantic(h: jax.Array, mask: jax.Array | None = None) -> jax.Array:
     return (h * m).sum(axis=-2) / jnp.maximum(m.sum(axis=-2), 1.0)
 
 
-def cosine_scores(sem: jax.Array, entries_j: jax.Array, class_mask: jax.Array) -> jax.Array:
+def cosine_scores(sem: jax.Array, entries_j: jax.Array, class_mask: jax.Array,
+                  scale_j: jax.Array | None = None) -> jax.Array:
     """C[·, i] — cosine similarity of pooled vectors vs. layer-``j`` entries.
 
     ``sem`` — (..., d); ``entries_j`` — (I, d); returns (..., I) with inactive
-    classes at ``NEG`` so they never win the top-2.
+    classes at ``NEG`` so they never win the top-2.  ``scale_j`` — (I,) per-
+    row scales of int8 ``entries_j``: a row's scale factors out of its dot
+    product, so it multiplies that class's score (exactly ``sem_n ·
+    (q_i * s_i)`` in real arithmetic; this order is the one the fused
+    kernels reproduce bit for bit).
     """
     sem_n = l2_normalize(sem)
-    c = sem_n @ entries_j.T  # entries are stored normalised
+    if scale_j is None:
+        c = sem_n @ entries_j.T  # entries are stored normalised
+    else:
+        c = (sem_n @ entries_j.astype(jnp.float32).T) * scale_j.astype(
+            jnp.float32)
     return jnp.where(class_mask, c, NEG)
 
 
@@ -261,17 +270,19 @@ def lookup_all_layers_ref(table: CacheTable, sems: jax.Array,
     CPU fallback; it is also the only path that materialises the full
     ``(B, L, I)`` accumulator (``acc``).
 
-    Quantized (int8) tables are dequantized up front — this defines the
-    reference semantics the fused quantized kernels reproduce (they fold the
-    identical elementwise ``q * scale`` into the slab load).
+    Quantized (int8) tables apply each row's scale to its dot product
+    (:func:`cosine_scores`) — this defines the reference semantics the fused
+    quantized kernels reproduce.
     """
-    table = dequantize_table(table)
     B = sems.shape[0]
     a0 = jnp.where(table.class_mask, 0.0, NEG) * jnp.ones((B, cfg.num_classes))
+    scales = (jnp.zeros((cfg.num_layers, 0)) if table.entry_scale is None
+              else table.entry_scale)
 
     def step(a_prev, inputs):
-        sem_j, entries_j, active_j = inputs
-        c = cosine_scores(sem_j, entries_j, table.class_mask)
+        sem_j, entries_j, scale_j, active_j = inputs
+        c = cosine_scores(sem_j, entries_j, table.class_mask,
+                          None if table.entry_scale is None else scale_j)
         a = accumulate(c, a_prev, cfg.alpha, table.class_mask)
         # Inactive layer: carry state unchanged, emit no score.
         a_out = jnp.where(active_j, a, a_prev)
@@ -281,7 +292,7 @@ def lookup_all_layers_ref(table: CacheTable, sems: jax.Array,
 
     sems_t = jnp.swapaxes(sems, 0, 1)                     # (L, B, d)
     _, (scores, preds, accs) = jax.lax.scan(
-        step, a0, (sems_t, table.entries, table.layer_mask))
+        step, a0, (sems_t, table.entries, scales, table.layer_mask))
     scores = jnp.swapaxes(scores, 0, 1)                   # (B, L)
     preds = jnp.swapaxes(preds, 0, 1)                     # (B, L)
     accs = jnp.swapaxes(accs, 0, 1)                       # (B, L, I)
@@ -297,7 +308,7 @@ def lookup_all_layers_ref(table: CacheTable, sems: jax.Array,
 
 
 def lookup_all_layers(table: CacheTable, sems: jax.Array, cfg: CacheConfig,
-                      *, impl: str = "auto") -> LookupResult:
+                      *, impl: str = "auto", mesh=None) -> LookupResult:
     """Run Eq. (1)/(2) across all L layers for a batch of tap vectors.
 
     Dispatches between the fused Pallas kernels
@@ -318,10 +329,24 @@ def lookup_all_layers(table: CacheTable, sems: jax.Array, cfg: CacheConfig,
     The fused paths return ``acc=None`` — they never materialise the
     ``(B, L, I)`` accumulator; callers needing ``acc`` must ask for
     ``impl="ref"``.
+
+    A stacked table — a leading client axis K on every leaf, ``sems``
+    (K, B, L, d) — looks up each client's batch in its own table, and every
+    result field gains the K axis.  The fused kernels take the stack in one
+    launch; this is how the round engine batches its clients (a ``vmap``
+    of the kernels does not lower on the TPU).
+
+    ``mesh`` — the mesh of a class-sharded cluster, whose gathered tables
+    are replicated on it: a kernel then runs whole on every device under
+    ``shard_map`` (JAX refuses to lower a Mosaic kernel in a multi-device
+    program outside one).
     """
     if impl == "auto":
         impl = "fused" if jax.default_backend() == "tpu" else "ref"
     if impl == "ref":
+        if table.entries.ndim == 4:
+            return jax.vmap(lambda t, s: lookup_all_layers_ref(t, s, cfg))(
+                table, sems)
         return lookup_all_layers_ref(table, sems, cfg)
     entry_dtype = "int8" if table.entry_scale is not None else "float32"
     if impl == "fused":
@@ -337,12 +362,20 @@ def lookup_all_layers(table: CacheTable, sems: jax.Array, cfg: CacheConfig,
                                             cache_lookup_all_layers_tiled)
     kernel = (cache_lookup_all_layers if impl == "fused_single"
               else cache_lookup_all_layers_tiled)
-    scores, preds, exit_layer = kernel(
-        sems, table.entries, table.class_mask, table.layer_mask,
-        cfg.theta_vec(), alpha=cfg.alpha, entry_scale=table.entry_scale)
+
+    def call(sems, table, theta):
+        return kernel(sems, table.entries, table.class_mask, table.layer_mask,
+                      theta, alpha=cfg.alpha, entry_scale=table.entry_scale)
+
+    if mesh is not None:
+        from jax.sharding import PartitionSpec as P
+        call = jax.shard_map(call, mesh=mesh, in_specs=P(), out_specs=P(),
+                             check_vma=False)
+    scores, preds, exit_layer = call(sems, table, cfg.theta_vec())
     hit = exit_layer < cfg.num_layers
     pred = jnp.take_along_axis(
-        preds, jnp.minimum(exit_layer, cfg.num_layers - 1)[:, None], axis=1)[:, 0]
+        preds, jnp.minimum(exit_layer, cfg.num_layers - 1)[..., None],
+        axis=-1)[..., 0]
     return LookupResult(hit=hit, exit_layer=exit_layer, pred=pred,
                         scores=scores, acc=None)
 

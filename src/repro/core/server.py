@@ -19,7 +19,8 @@ At scale the table is sharded over the class axis I
 (:func:`repro.distributed.sharding.shard_server_state`): every update here is
 elementwise in I (the Eq.-4 weights, the merge, the L2-normalise over d, the
 Φ add), so a class-sharded ServerState flows through ``global_update_body``
-with no cross-device communication — GSPMD keeps I split end to end.  The
+with no cross-device communication — GSPMD keeps I split end to end, and
+the fused Pallas merge runs per class shard under ``shard_map``.  The
 round driver (:mod:`repro.core.simulation`) gathers ``entries`` only at
 client subtable allocation.
 """
@@ -107,13 +108,40 @@ def global_update_body(server: ServerState, up: ClientUpload,
 global_update = partial(jax.jit, static_argnames=("scfg",))(global_update_body)
 
 
+def _fused_merge(server: ServerState, uploads: ClientUpload,
+                 include: jax.Array, gamma: float, mesh):
+    """The Pallas merge of a round's uploads, per device shard of the class
+    axis when ``mesh`` splits it (Eq. 4/5 are elementwise in I, so every
+    device merges its own classes and nothing crosses devices).  JAX refuses
+    to lower a Mosaic kernel in a multi-device program outside
+    ``shard_map``, so a mesh whose axis does not divide I runs the whole
+    merge on every device."""
+    from repro.kernels.cache_merge import cache_merge_round
+    merge = partial(cache_merge_round, gamma=gamma)
+    args = (server.entries, server.phi_global, uploads.u, uploads.phi,
+            uploads.u_touched, include)
+    if mesh is None:
+        return merge(*args)
+    from jax.sharding import PartitionSpec as P
+    from repro.distributed.sharding import class_axis, fit_spec
+    ax = fit_spec(P(class_axis(mesh)), server.phi_global.shape, mesh)[0]
+    return jax.shard_map(
+        merge, mesh=mesh,
+        in_specs=(P(None, ax, None), P(ax), P(None, None, ax, None),
+                  P(None, ax), P(None, None, ax), P()),
+        out_specs=(P(None, ax, None), P(ax)), check_vma=False)(*args)
+
+
 def merge_round(server: ServerState, uploads: ClientUpload,
-                include: jax.Array, scfg: ServerConfig) -> ServerState:
+                include: jax.Array, scfg: ServerConfig,
+                mesh=None) -> ServerState:
     """Merge one round's stacked uploads (leading K axis) in client order.
 
     ``include`` — (K,) bool; an excluded client's Eq.-4/5 update is a no-op
-    (straggler deadline, fault quarantine).  Dispatch per
-    ``scfg.merge_impl``:
+    (straggler deadline, fault quarantine).  ``mesh`` — the mesh a
+    class-sharded ServerState lives on (:func:`repro.distributed.sharding.
+    shard_server_state`); the fused merge then runs per class shard.
+    Dispatch per ``scfg.merge_impl``:
 
     * ``"ref"``   — ``lax.scan`` of :func:`global_update_body` with the
       include gate applied tree-wide: the bit-for-bit oracle, and the only
@@ -143,10 +171,8 @@ def merge_round(server: ServerState, uploads: ClientUpload,
     if impl != "fused":
         raise ValueError(f"unknown merge impl: {impl!r}")
 
-    from repro.kernels.cache_merge import cache_merge_round
-    entries, phi_global = cache_merge_round(
-        server.entries, server.phi_global, uploads.u, uploads.phi,
-        uploads.u_touched, include, gamma=scfg.gamma)
+    entries, phi_global = _fused_merge(server, uploads, include,
+                                       scfg.gamma, mesh)
 
     # R-estimate EMA: same ops in the same (client) order as the reference.
     def rstep(r, inp):
@@ -164,7 +190,8 @@ def merge_round(server: ServerState, uploads: ClientUpload,
                        r_est=r_est, upsilon=server.upsilon)
 
 
-merge_round_jit = partial(jax.jit, static_argnames=("scfg",))(merge_round)
+merge_round_jit = partial(jax.jit,
+                          static_argnames=("scfg", "mesh"))(merge_round)
 
 
 # ---------------------------------------------------------------------------

@@ -12,7 +12,7 @@ simulator dispatches to (:func:`repro.core.semantic_cache.lookup_all_layers`).
 
     for j in 0..L-1:                      # unrolled inside the kernel
         sem_n = sem_j / ||sem_j||                     (VPU)
-        for t in class tiles:                         # unrolled inside
+        for t in class tiles:                         # rolled fori_loop
             C_t   = sem_n @ entries[j, t]ᵀ            (MXU matmul)
             A_t   = C_t + α·A_prev_t  (masked)        (Eq. 1)
             merge running top-2 / argmax              (VREG-resident)
@@ -21,14 +21,15 @@ simulator dispatches to (:func:`repro.core.semantic_cache.lookup_all_layers`).
 
 Design / tiling (recorded per the PR-1 plan):
 
-* **Grid = batch tiles only** ``(⌈B/B_TILE⌉,)``.  Layers and class tiles
-  are iterated *inside* the kernel body so the Eq.-1 accumulator ``A``
-  (``(B_TILE, I_pad)`` f32 scratch), the normalised tap vector, and the
-  running top-2/argmax state all stay **VMEM-resident for the whole
-  L-layer sweep** — the ``(B, L, I)`` accumulator tensor that the unfused
-  ``lax.scan`` round-trips through HBM on every round is never
-  materialised.  Only ``(B, L)`` scores, ``(B, L)`` per-layer argmax
-  classes, and the ``(B,)`` first-hit exit layer leave the kernel.
+* **Grid = (tables, batch tiles)** ``(K, ⌈B/B_TILE⌉)`` — K stacked
+  client tables, 1 for a single table.  Layers and class tiles are
+  iterated *inside* the kernel body so the Eq.-1 accumulator ``A``
+  (``(I_pad/I_TILE, B_TILE, I_TILE)`` f32 scratch), the normalised tap
+  vector, and the running top-2/argmax state all stay **VMEM-resident for
+  the whole L-layer sweep** — the ``(B, L, I)`` accumulator tensor that the
+  unfused ``lax.scan`` round-trips through HBM on every round is never
+  materialised.  Only ``(B, L)`` scores and ``(B, L+1)`` per-layer argmax
+  classes plus the first-hit exit layer leave the kernel.
 * **VMEM budget**: entries ``(L, I_pad, d)`` + accumulator
   ``(B_TILE, I_pad)`` + taps ``(B_TILE, L, d)``.  At paper scale
   (L=24, I≤1024, d=64, B_TILE=128) that is ≈6.5 MB < the ~16 MB/core
@@ -43,9 +44,10 @@ Design / tiling (recorded per the PR-1 plan):
   ``I`` are zero/NEG-padded to tile multiples, padded classes are masked
   to ``NEG`` so they never enter the top-2, and padded batch rows are
   sliced off on return.
-* ``interpret`` defaults to auto-detection: interpreted on CPU (this
-  container), compiled on an actual TPU backend.  TPU-native numbers are
-  still an open validation item (ROADMAP).
+* ``interpret`` defaults to auto-detection: interpreted on CPU, compiled
+  by Mosaic on a TPU backend.  ``tests/test_tpu_compile.py`` compiles every
+  kernel here for a described TPU v5e at serving widths; ``chip_smoke.py``
+  runs them on the chip against the reference.
 
 The paper measures the *unfused* all-layer lookup bill at 56 % of a
 no-cache forward; the win here is (a) one kernel launch instead of L
@@ -69,6 +71,11 @@ from repro.kernels.common import pick_class_block
 from repro.kernels.common import resolve_interpret as _resolve_interpret
 
 NEG = -1e9
+
+# f32 cosine scores contract in full fp32 on the MXU, not in one bf16 pass:
+# hit decisions compare score gaps against Θ, and the reference path
+# (lookup_all_layers_ref) is exact f32.  Interpret mode ignores it.
+_DOT_PRECISION = jax.lax.Precision.HIGHEST
 
 
 # ---------------------------------------------------------------------------
@@ -183,73 +190,137 @@ def cache_lookup_layer(sem: jax.Array, entries: jax.Array, class_mask: jax.Array
 # fused all-layer kernel (the simulator hot path)
 # ---------------------------------------------------------------------------
 
+def _top2_merge(at, lo, m1, m2, a1):
+    """Fold one class tile's Eq.-1 scores ``at`` (B_t, I_t), whose first
+    column is global class ``lo``, into the running top-2/argmax state."""
+    cols = jax.lax.broadcasted_iota(jnp.int32, at.shape, 1) + lo
+    b1 = jnp.max(at, axis=1)
+    ba1 = jnp.argmax(at, axis=1).astype(jnp.int32) + lo
+    b2 = jnp.max(jnp.where(cols == ba1[:, None], NEG, at), axis=1)
+    return (jnp.maximum(m1, b1),
+            jnp.maximum(jnp.maximum(m2, b2), jnp.minimum(m1, b1)),
+            jnp.where(b1 > m1, ba1, a1))
+
+
+def _eq2_score(m1, m2, active):
+    """Eq. (2) discriminative score, with the <2-active-classes guard."""
+    d = jnp.where(m2 > 1e-6, (m1 - m2) / jnp.maximum(m2, 1e-6), 0.0)
+    d = jnp.where(m2 <= NEG / 2, 0.0, d)
+    return jnp.where(active, d, 0.0)
+
+
+def _dequantize_scores(c, scale_row):
+    """int8 rows: the per-row scale factors out of the dot product, so it
+    scales the score columns — the same order of operations as
+    :func:`repro.core.semantic_cache.cosine_scores`.  A scale row is
+    lane-major, which the columns of ``c`` are too; scaling the int8 rows
+    before the dot would need the scales as a sublane-major column, a
+    relayout that costs several MiB of VMEM."""
+    return c * scale_row.astype(jnp.float32)
+
+
+def _normalized_tap(sem_ref, j):
+    s = sem_ref[j].astype(jnp.float32)                        # (B_t, d)
+    return s / (jnp.sqrt(jnp.sum(s * s, axis=1, keepdims=True)) + 1e-8)
+
+
 def _kernel_all(sem_ref, entries_ref, cmask_ref, lmask_ref, theta_ref,
                 *args,
                 alpha: float, num_layers: int, n_i_tiles: int,
                 quantized: bool):
     if quantized:
-        (scale_ref, score_ref, pred_ref, exit_ref, a_ref) = args
+        (scale_ref, score_ref, pred_ref, a_ref) = args
     else:
-        (score_ref, pred_ref, exit_ref, a_ref) = args
+        (score_ref, pred_ref, a_ref) = args
         scale_ref = None
-    bt = a_ref.shape[0]
+    bt = score_ref.shape[0]
+    k = pl.program_id(0)                                      # table
 
-    # Eq.-1 accumulator A: 0 for active classes, NEG for inactive/padded —
-    # VMEM-resident across the full layer sweep.
-    cmask = cmask_ref[...] > 0                                # (I_pad,)
-    a_ref[...] = jnp.where(cmask[None, :], 0.0, NEG) * jnp.ones((bt, 1))
+    # Eq.-1 accumulator A, one (B_t, I_TILE) slab per class tile: 0 for
+    # active classes, NEG for inactive/padded — VMEM-resident across the
+    # full layer sweep.
+    def init_tile(it, carry):
+        a_ref[it] = jnp.where(cmask_ref[it] > 0, 0.0, NEG) * jnp.ones((bt, 1))
+        return carry
 
+    jax.lax.fori_loop(0, n_i_tiles, init_tile, 0)
     exit_layer = jnp.full((bt,), num_layers, jnp.int32)
 
     for j in range(num_layers):
-        s = sem_ref[:, j, :].astype(jnp.float32)              # (B_t, d)
-        norm = jnp.sqrt(jnp.sum(s * s, axis=1, keepdims=True)) + 1e-8
-        semn = s / norm
+        semn = _normalized_tap(sem_ref, j)
+        active = lmask_ref[k, j] > 0                          # SMEM scalar
 
-        active = lmask_ref[j] > 0
-
-        # Running top-2/argmax across class tiles (VREG-resident).
-        m1 = jnp.full((bt,), NEG, jnp.float32)
-        m2 = jnp.full((bt,), NEG, jnp.float32)
-        a1 = jnp.zeros((bt,), jnp.int32)
-        for it in range(n_i_tiles):
-            lo = it * I_TILE
-            e = entries_ref[j, lo:lo + I_TILE, :].astype(jnp.float32)
-            if quantized:
-                # Same elementwise q * scale the reference path materialises
-                # (lookup_all_layers_ref dequantizes up front) — bitwise-equal
-                # dequantized operands feed the identical MXU dot.
-                s = scale_ref[j, lo:lo + I_TILE].astype(jnp.float32)
-                e = e * s[:, None]
-            c = jnp.dot(semn, e.T,
+        # Running top-2/argmax across class tiles (a rolled loop: the
+        # kernel's size, and its compile time, do not grow with I).
+        def tile_step(it, carry, j=j, semn=semn, active=active):
+            lo = pl.multiple_of(it * I_TILE, I_TILE)
+            e = entries_ref[j, pl.ds(lo, I_TILE), :].astype(jnp.float32)
+            c = jnp.dot(semn, e.T, precision=_DOT_PRECISION,
                         preferred_element_type=jnp.float32)   # (B_t, I_t)
-            apv = a_ref[:, lo:lo + I_TILE]
-            mt = cmask[lo:lo + I_TILE]
-            at = jnp.where(mt[None, :], c + alpha * apv, NEG)  # Eq. (1)
+            if quantized:
+                c = _dequantize_scores(c, scale_ref[it, pl.ds(j, 1), :])
+            apv = a_ref[it]
+            at = jnp.where(cmask_ref[it] > 0, c + alpha * apv, NEG)  # Eq. (1)
             # Inactive layer: carry the accumulator state unchanged.
-            a_ref[:, lo:lo + I_TILE] = jnp.where(active, at, apv)
+            a_ref[it] = jnp.where(active, at, apv)
+            return _top2_merge(at, lo, *carry)
 
-            cols = jax.lax.broadcasted_iota(jnp.int32, at.shape, 1) + lo
-            b1 = jnp.max(at, axis=1)
-            ba1 = jnp.argmax(at, axis=1).astype(jnp.int32) + lo
-            b2 = jnp.max(jnp.where(cols == ba1[:, None], NEG, at), axis=1)
-            new_m1 = jnp.maximum(m1, b1)
-            a1 = jnp.where(b1 > m1, ba1, a1)
-            m2 = jnp.maximum(jnp.maximum(m2, b2), jnp.minimum(m1, b1))
-            m1 = new_m1
+        m1, m2, a1 = jax.lax.fori_loop(
+            0, n_i_tiles, tile_step,
+            (jnp.full((bt,), NEG, jnp.float32),
+             jnp.full((bt,), NEG, jnp.float32),
+             jnp.zeros((bt,), jnp.int32)))
 
-        # Eq. (2) discriminative score, with the <2-active-classes guard.
-        d = jnp.where(m2 > 1e-6, (m1 - m2) / jnp.maximum(m2, 1e-6), 0.0)
-        d = jnp.where(m2 <= NEG / 2, 0.0, d)
-        d = jnp.where(active, d, 0.0)
-
+        d = _eq2_score(m1, m2, active)
         score_ref[:, j] = d
         pred_ref[:, j] = a1
         hit_j = active & (d > theta_ref[j])
         exit_layer = jnp.where((exit_layer == num_layers) & hit_j,
                                j, exit_layer)
 
-    exit_ref[...] = exit_layer
+    pred_ref[:, num_layers] = exit_layer
+
+
+def _smem_spec():
+    return pl.BlockSpec(memory_space=pltpu.SMEM)
+
+
+def _resident(block_shape, index_map):
+    """A block that changes only with the table index: one VMEM buffer,
+    not the pipeline's two (the whole table is the VMEM budget)."""
+    return pl.BlockSpec(block_shape, index_map, pipeline_mode=pl.Buffered(1))
+
+
+def _with_table_axis(sems, entries, class_mask, layer_mask, entry_scale):
+    """Operands with a leading table axis K (stacked per-client tables, as
+    ``round_step`` passes them); unbatched operands get K=1.  Returns the
+    operands and whether the outputs drop the axis again."""
+    if entries.ndim == 4:
+        return (sems, entries, class_mask, layer_mask, entry_scale), False
+    return (sems[None], entries[None], class_mask[None], layer_mask[None],
+            None if entry_scale is None else entry_scale[None]), True
+
+
+def _flat_batch_outputs(K, Bp, nb, L):
+    """Output shapes and specs over the table-major (K·Bp) batch rows:
+    Eq.-2 scores (K·Bp, L), and the per-layer argmax classes with the
+    first-hit exit layer as column L (K·Bp, L+1) — 2-D blocks, which the
+    TPU lays out as the kernel does for any K."""
+    shapes = (jax.ShapeDtypeStruct((K * Bp, L), jnp.float32),
+              jax.ShapeDtypeStruct((K * Bp, L + 1), jnp.int32))
+    specs = (pl.BlockSpec((B_TILE, L), lambda k, b: (k * nb + b, 0)),
+             pl.BlockSpec((B_TILE, L + 1), lambda k, b: (k * nb + b, 0)))
+    return shapes, specs
+
+
+def _unflatten(outs, K, Bp, B, L, squeeze):
+    scores, preds = outs
+    scores = scores.reshape(K, Bp, L)[:, :B]
+    preds = preds.reshape(K, Bp, L + 1)[:, :B]
+    preds, exit_layer = preds[..., :L], preds[..., L]
+    if squeeze:
+        return scores[0], preds[0], exit_layer[0]
+    return scores, preds, exit_layer
 
 
 @functools.partial(jax.jit, static_argnames=("alpha", "interpret"))
@@ -266,54 +337,62 @@ def cache_lookup_all_layers(sems: jax.Array, entries: jax.Array,
     per-layer Θ.  Returns (scores (B, L) f32, preds (B, L) i32, exit_layer
     (B,) i32 with L meaning "no hit").  The (B, L, I) accumulator never
     touches HBM.
+
+    A leading table axis K on ``sems``/``entries``/``class_mask``/
+    ``layer_mask``/``entry_scale`` looks up K stacked tables (one per
+    client) in the same launch — grid ``(K, ⌈B/B_TILE⌉)`` — and adds K to
+    the outputs.  (The TPU lowering cannot ``vmap`` this kernel: its SMEM
+    operands would need a block per table.)
+
+    Kernel-side layouts: taps arrive layer-major ``(L, K·B, d)`` and the
+    class mask / scale planes class-tile-major, so every dynamic index in
+    the kernel is on a leading axis or an aligned sublane offset; the
+    per-layer scalars (layer mask, Θ) live in SMEM.
     """
     interpret = _resolve_interpret(interpret)
-    B, L, d = sems.shape
-    I = entries.shape[1]
+    (sems, entries, class_mask, layer_mask, entry_scale), squeeze = \
+        _with_table_axis(sems, entries, class_mask, layer_mask, entry_scale)
+    K, B, L, d = sems.shape
+    I = entries.shape[2]
     Bp = -(-B // B_TILE) * B_TILE
     Ip = -(-I // I_TILE) * I_TILE
-    semp = jnp.pad(sems, ((0, Bp - B), (0, 0), (0, 0)))
-    ep = jnp.pad(entries, ((0, 0), (0, Ip - I), (0, 0)))
-    cmp_ = jnp.pad(class_mask.astype(jnp.int32), (0, Ip - I))
-    lmp = layer_mask.astype(jnp.int32)
-    thp = theta.astype(jnp.float32)
-    n_i = Ip // I_TILE
+    n_i, nb = Ip // I_TILE, Bp // B_TILE
+    semp = jnp.pad(sems, ((0, 0), (0, Bp - B), (0, 0), (0, 0)))
+    semp = jnp.transpose(semp, (2, 0, 1, 3)).reshape(L, K * Bp, d)
+    ep = jnp.pad(entries, ((0, 0), (0, 0), (0, Ip - I), (0, 0)))
+    cmp_ = jnp.pad(class_mask.astype(jnp.int32), ((0, 0), (0, Ip - I)))
     quantized = entry_scale is not None
 
-    inputs = [semp, ep, cmp_, lmp, thp]
+    inputs = [semp, ep.reshape(K * L, Ip, d),
+              cmp_.reshape(K * n_i, 1, I_TILE),
+              layer_mask.astype(jnp.int32), theta.astype(jnp.float32)]
     in_specs = [
-        pl.BlockSpec((B_TILE, L, d), lambda b: (b, 0, 0)),
-        pl.BlockSpec((L, Ip, d), lambda b: (0, 0, 0)),
-        pl.BlockSpec((Ip,), lambda b: (0,)),
-        pl.BlockSpec((L,), lambda b: (0,)),
-        pl.BlockSpec((L,), lambda b: (0,)),
+        pl.BlockSpec((L, B_TILE, d), lambda k, b: (0, k * nb + b, 0)),
+        _resident((L, Ip, d), lambda k, b: (k, 0, 0)),
+        _resident((n_i, 1, I_TILE), lambda k, b: (k, 0, 0)),
+        _smem_spec(),
+        _smem_spec(),
     ]
     if quantized:
-        inputs.append(jnp.pad(entry_scale, ((0, 0), (0, Ip - I))))
-        in_specs.append(pl.BlockSpec((L, Ip), lambda b: (0, 0)))
+        sp = jnp.pad(entry_scale, ((0, 0), (0, 0), (0, Ip - I)))
+        sp = jnp.transpose(sp.reshape(K, L, n_i, I_TILE), (0, 2, 1, 3))
+        inputs.append(sp.reshape(K * n_i, L, I_TILE))
+        in_specs.append(_resident((n_i, L, I_TILE), lambda k, b: (k, 0, 0)))
 
-    out_shapes = (
-        jax.ShapeDtypeStruct((Bp, L), jnp.float32),    # scores
-        jax.ShapeDtypeStruct((Bp, L), jnp.int32),      # per-layer argmax
-        jax.ShapeDtypeStruct((Bp,), jnp.int32),        # first-hit exit layer
-    )
-    scores, preds, exit_layer = pl.pallas_call(
+    out_shapes, out_specs = _flat_batch_outputs(K, Bp, nb, L)
+    outs = pl.pallas_call(
         functools.partial(_kernel_all, alpha=alpha, num_layers=L,
                           n_i_tiles=n_i, quantized=quantized),
-        grid=(Bp // B_TILE,),
+        grid=(K, nb),
         in_specs=in_specs,
-        out_specs=(
-            pl.BlockSpec((B_TILE, L), lambda b: (b, 0)),
-            pl.BlockSpec((B_TILE, L), lambda b: (b, 0)),
-            pl.BlockSpec((B_TILE,), lambda b: (b,)),
-        ),
+        out_specs=out_specs,
         scratch_shapes=[
-            pltpu.VMEM((B_TILE, Ip), jnp.float32),     # Eq.-1 accumulator A
+            pltpu.VMEM((n_i, B_TILE, I_TILE), jnp.float32),   # Eq.-1 acc. A
         ],
         out_shape=out_shapes,
         interpret=interpret,
     )(*inputs)
-    return scores[:B], preds[:B], exit_layer[:B]
+    return _unflatten(outs, K, Bp, B, L, squeeze)
 
 
 # ---------------------------------------------------------------------------
@@ -339,27 +418,26 @@ def _kernel_all_tiled(sem_ref, entries_hbm, cmask_hbm, lmask_ref, theta_ref,
     is batch tiles only).
     """
     if quantized:
-        (scale_hbm, score_ref, pred_ref, exit_ref,
+        (scale_hbm, score_ref, pred_ref,
          ent_sl, msk_sl, scl_sl, dma_sems) = args
     else:
-        (score_ref, pred_ref, exit_ref, ent_sl, msk_sl, dma_sems) = args
+        (score_ref, pred_ref, ent_sl, msk_sl, dma_sems) = args
         scale_hbm = scl_sl = None
     bt = score_ref.shape[0]
+    k = pl.program_id(0)                              # table
 
     def ent_dma(slot, t):
         return pltpu.make_async_copy(
-            entries_hbm.at[:, pl.ds(t * i_block, i_block), :],
+            entries_hbm.at[k, :, pl.ds(t * i_block, i_block), :],
             ent_sl.at[slot], dma_sems.at[slot, 0])
 
     def msk_dma(slot, t):
         return pltpu.make_async_copy(
-            cmask_hbm.at[pl.ds(t * i_block, i_block)],
-            msk_sl.at[slot], dma_sems.at[slot, 1])
+            cmask_hbm.at[k, t], msk_sl.at[slot], dma_sems.at[slot, 1])
 
     def scl_dma(slot, t):
         return pltpu.make_async_copy(
-            scale_hbm.at[:, pl.ds(t * i_block, i_block)],
-            scl_sl.at[slot], dma_sems.at[slot, 2])
+            scale_hbm.at[k, t], scl_sl.at[slot], dma_sems.at[slot, 2])
 
     def start(slot, t):
         ent_dma(slot, t).start()
@@ -375,13 +453,10 @@ def _kernel_all_tiled(sem_ref, entries_hbm, cmask_hbm, lmask_ref, theta_ref,
 
     start(0, 0)                                       # warm-up: block 0
 
-    # Normalise the taps once for the whole block sweep.
-    s = sem_ref[...].astype(jnp.float32)              # (B_t, L, d)
-    norm = jnp.sqrt(jnp.sum(s * s, axis=2, keepdims=True)) + 1e-8
-    semn_all = s / norm
-
     def block_step(t, carry):
-        m1c, m2c, a1c = carry                         # (B_t, L) each
+        # One (B_t,) running top-1 / top-2 / argmax triple per layer: L
+        # separate carries, so no per-layer column update (a scatter the
+        # TPU lowering refuses) is ever needed.
         slot = jax.lax.rem(t, 2)
 
         @pl.when(t + 1 < n_c_blocks)
@@ -390,51 +465,42 @@ def _kernel_all_tiled(sem_ref, entries_hbm, cmask_hbm, lmask_ref, theta_ref,
 
         wait(slot, t)
         lo = t * i_block                  # global class offset of this block
-        cmask = msk_sl[slot] > 0                      # (i_block,)
-        a_prev = jnp.where(cmask[None, :], 0.0, NEG) * jnp.ones((bt, 1))
+        cmask = msk_sl[slot] > 0                      # (1, i_block)
+        a_prev = jnp.where(cmask, 0.0, NEG) * jnp.ones((bt, 1))
 
+        out = []
         for j in range(num_layers):
-            semn = semn_all[:, j, :]
-            active = lmask_ref[j] > 0
+            semn = _normalized_tap(sem_ref, j)
+            active = lmask_ref[k, j] > 0              # SMEM scalar
 
             e = ent_sl[slot, j].astype(jnp.float32)   # (i_block, d)
-            if quantized:
-                e = e * scl_sl[slot, j].astype(jnp.float32)[:, None]
-            c = jnp.dot(semn, e.T,
+            c = jnp.dot(semn, e.T, precision=_DOT_PRECISION,
                         preferred_element_type=jnp.float32)  # (B_t, i_block)
-            at = jnp.where(cmask[None, :], c + alpha * a_prev, NEG)  # Eq. (1)
+            if quantized:
+                c = _dequantize_scores(c, scl_sl[slot, pl.ds(j, 1), :])
+            at = jnp.where(cmask, c + alpha * a_prev, NEG)   # Eq. (1)
             # Inactive layer: carry the accumulator state unchanged.
             a_prev = jnp.where(active, at, a_prev)
-
             # Block-local top-2, merged into the carried per-layer state.
-            cols = jax.lax.broadcasted_iota(jnp.int32, at.shape, 1) + lo
-            b1 = jnp.max(at, axis=1)
-            ba1 = jnp.argmax(at, axis=1).astype(jnp.int32) + lo
-            b2 = jnp.max(jnp.where(cols == ba1[:, None], NEG, at), axis=1)
-            m1, m2, a1 = m1c[:, j], m2c[:, j], a1c[:, j]
-            a1c = a1c.at[:, j].set(jnp.where(b1 > m1, ba1, a1))
-            m2c = m2c.at[:, j].set(jnp.maximum(jnp.maximum(m2, b2),
-                                               jnp.minimum(m1, b1)))
-            m1c = m1c.at[:, j].set(jnp.maximum(m1, b1))
-        return m1c, m2c, a1c
+            out.append(_top2_merge(at, lo, *carry[j]))
+        return tuple(out)
 
-    m1, m2, a1 = jax.lax.fori_loop(
-        0, n_c_blocks, block_step,
-        (jnp.full((bt, num_layers), NEG, jnp.float32),
-         jnp.full((bt, num_layers), NEG, jnp.float32),
-         jnp.zeros((bt, num_layers), jnp.int32)))
+    init = tuple((jnp.full((bt,), NEG, jnp.float32),
+                  jnp.full((bt,), NEG, jnp.float32),
+                  jnp.zeros((bt,), jnp.int32)) for _ in range(num_layers))
+    state = jax.lax.fori_loop(0, n_c_blocks, block_step, init)
 
-    # All blocks merged: Eq. (2) + first-hit exit.
-    d = jnp.where(m2 > 1e-6, (m1 - m2) / jnp.maximum(m2, 1e-6), 0.0)
-    d = jnp.where(m2 <= NEG / 2, 0.0, d)
-    active = lmask_ref[...] > 0                       # (L,)
-    d = jnp.where(active[None, :], d, 0.0)
-    score_ref[...] = d
-    pred_ref[...] = a1
-    hits = active[None, :] & (d > theta_ref[...][None, :])
-    first = jnp.argmax(hits, axis=1).astype(jnp.int32)
-    exit_ref[...] = jnp.where(hits.any(axis=1), first,
-                              num_layers).astype(jnp.int32)
+    # All blocks merged: Eq. (2) + first-hit exit, layer by layer.
+    exit_layer = jnp.full((bt,), num_layers, jnp.int32)
+    for j, (m1, m2, a1) in enumerate(state):
+        active = lmask_ref[k, j] > 0
+        d = _eq2_score(m1, m2, active)
+        score_ref[:, j] = d
+        pred_ref[:, j] = a1
+        hit_j = active & (d > theta_ref[j])
+        exit_layer = jnp.where((exit_layer == num_layers) & hit_j,
+                               j, exit_layer)
+    pred_ref[:, num_layers] = exit_layer
 
 
 @functools.partial(jax.jit, static_argnames=("alpha", "i_block", "interpret"))
@@ -454,15 +520,18 @@ def cache_lookup_all_layers_tiled(sems: jax.Array, entries: jax.Array,
     block ``t+1`` while block ``t`` computes (double buffering).  VMEM use is
     O(``2·L·i_block·d``) instead of O(``L·I·d``), so ``I`` is bounded by HBM,
     not VMEM.  Quantized (int8 + bf16 scale) tables stream a third slab of
-    per-row scales and dequantize in-register after the copy.
+    per-row scales that multiply the score columns after the dot.
 
     ``i_block`` — class-block width (rounded to an ``I_TILE`` multiple);
     ``None`` picks the largest block whose working set fits the budget
-    (:func:`repro.kernels.common.pick_class_block`).
+    (:func:`repro.kernels.common.pick_class_block`).  A leading table axis
+    batches K tables into one launch, as for the single-pass kernel.
     """
     interpret = _resolve_interpret(interpret)
-    B, L, d = sems.shape
-    I = entries.shape[1]
+    (sems, entries, class_mask, layer_mask, entry_scale), squeeze = \
+        _with_table_axis(sems, entries, class_mask, layer_mask, entry_scale)
+    K, B, L, d = sems.shape
+    I = entries.shape[2]
     quantized = entry_scale is not None
     if i_block is None:
         i_block = pick_class_block(
@@ -470,52 +539,51 @@ def cache_lookup_all_layers_tiled(sems: jax.Array, entries: jax.Array,
     i_block = max(I_TILE, (i_block // I_TILE) * I_TILE)
     Bp = -(-B // B_TILE) * B_TILE
     Ip = -(-I // i_block) * i_block
-    semp = jnp.pad(sems, ((0, Bp - B), (0, 0), (0, 0)))
-    ep = jnp.pad(entries, ((0, 0), (0, Ip - I), (0, 0)))
-    cmp_ = jnp.pad(class_mask.astype(jnp.int32), (0, Ip - I))
-    lmp = layer_mask.astype(jnp.int32)
-    thp = theta.astype(jnp.float32)
-    n_c = Ip // i_block
+    n_c, nb = Ip // i_block, Bp // B_TILE
+    semp = jnp.pad(sems, ((0, 0), (0, Bp - B), (0, 0), (0, 0)))
+    semp = jnp.transpose(semp, (2, 0, 1, 3)).reshape(L, K * Bp, d)
+    ep = jnp.pad(entries, ((0, 0), (0, 0), (0, Ip - I), (0, 0)))
+    cmp_ = jnp.pad(class_mask.astype(jnp.int32),
+                   ((0, 0), (0, Ip - I))).reshape(K, n_c, 1, i_block)
 
-    any_spec = pl.BlockSpec(memory_space=pltpu.TPUMemorySpace.ANY)
-    inputs = [semp, ep, cmp_, lmp, thp]
+    any_spec = pl.BlockSpec(memory_space=pl.ANY)
+    inputs = [semp, ep, cmp_, layer_mask.astype(jnp.int32),
+              theta.astype(jnp.float32)]
     in_specs = [
-        pl.BlockSpec((B_TILE, L, d), lambda b: (b, 0, 0)),
+        pl.BlockSpec((L, B_TILE, d), lambda k, b: (0, k * nb + b, 0)),
         any_spec,                                      # entries: kernel DMAs
         any_spec,                                      # class mask: ditto
-        pl.BlockSpec((L,), lambda b: (0,)),
-        pl.BlockSpec((L,), lambda b: (0,)),
+        _smem_spec(),
+        _smem_spec(),
     ]
     n_dma = 2
     scratch = [
         pltpu.VMEM((2, L, i_block, d), ep.dtype),      # entry slabs (2 slots)
-        pltpu.VMEM((2, i_block), jnp.int32),           # class-mask slabs
+        pltpu.VMEM((2, 1, i_block), jnp.int32),        # class-mask slabs
     ]
     if quantized:
-        inputs.append(jnp.pad(entry_scale, ((0, 0), (0, Ip - I))))
+        # Block-major (K, n_c, Lp, i_block) scale plane: one whole-tile DMA
+        # per block.  Layers pad to the 16-row bf16 sublane tile — a 12-row
+        # window of a tiled bf16 plane is not a legal DMA.
+        Lp = -(-L // 16) * 16
+        sp = jnp.pad(entry_scale, ((0, 0), (0, Lp - L), (0, Ip - I)))
+        inputs.append(jnp.transpose(sp.reshape(K, Lp, n_c, i_block),
+                                    (0, 2, 1, 3)))
         in_specs.append(any_spec)                      # scales: kernel DMAs
-        scratch.append(pltpu.VMEM((2, L, i_block), entry_scale.dtype))
+        scratch.append(pltpu.VMEM((2, Lp, i_block), entry_scale.dtype))
         n_dma = 3
     scratch.append(pltpu.SemaphoreType.DMA((2, n_dma)))
 
-    out_shapes = (
-        jax.ShapeDtypeStruct((Bp, L), jnp.float32),    # scores
-        jax.ShapeDtypeStruct((Bp, L), jnp.int32),      # per-layer argmax
-        jax.ShapeDtypeStruct((Bp,), jnp.int32),        # first-hit exit layer
-    )
-    scores, preds, exit_layer = pl.pallas_call(
+    out_shapes, out_specs = _flat_batch_outputs(K, Bp, nb, L)
+    outs = pl.pallas_call(
         functools.partial(_kernel_all_tiled, alpha=alpha, num_layers=L,
                           n_c_blocks=n_c, i_block=i_block,
                           quantized=quantized),
-        grid=(Bp // B_TILE,),
+        grid=(K, nb),
         in_specs=in_specs,
-        out_specs=(
-            pl.BlockSpec((B_TILE, L), lambda b: (b, 0)),
-            pl.BlockSpec((B_TILE, L), lambda b: (b, 0)),
-            pl.BlockSpec((B_TILE,), lambda b: (b,)),
-        ),
+        out_specs=out_specs,
         scratch_shapes=scratch,
         out_shape=out_shapes,
         interpret=interpret,
     )(*inputs)
-    return scores[:B], preds[:B], exit_layer[:B]
+    return _unflatten(outs, K, Bp, B, L, squeeze)

@@ -18,7 +18,7 @@ batch in one ``pallas_call``**:
             (both gated on the round's include mask)
         entries[.., i, ..], Φ[i] ← scratch        (k == K-1)
 
-The running ``(L, I_TILE, d)`` entries block and ``(I_TILE,)`` frequency
+The running ``(L, I_TILE, d)`` entries block and ``(1, I_TILE)`` frequency
 block live in VMEM scratch across the K revisits, so the table crosses HBM
 exactly twice per round (one read, one write) instead of 2·K times — round
 boundaries stop being host-visible scan steps.
@@ -61,18 +61,20 @@ def _kernel_merge(entries0_ref, phi0_ref, u_ref, phik_ref, touched_ref,
         phi_s[...] = phi0_ref[...]
 
     # One client's Eq.-4/5 update — identical ops to global_update_body.
-    phi_l = phik_ref[0].astype(jnp.float32)                   # (I_t,)
+    phi_l = phik_ref[0].astype(jnp.float32)                   # (1, I_t)
     phi_g = phi_s[...]
     denom = jnp.maximum(phi_g + phi_l, 1e-6)
-    w_g = (gamma * phi_g / denom)[None, :, None]              # (1, I_t, 1)
-    w_l = (phi_l / denom)[None, :, None]
+    w_g = (gamma * phi_g / denom)[..., None]                  # (1, I_t, 1)
+    w_l = (phi_l / denom)[..., None]
     ent = ent_s[...]                                          # (L, I_t, d)
     merged = l2_normalize(w_g * ent + w_l * l2_normalize(u_ref[0]))
-    touched = touched_ref[0] > 0                              # (L, I_t)
-    new_ent = jnp.where(touched[..., None], merged, ent)
+    # Broadcast the (L, I_t) touched mask over d as f32: Mosaic cannot
+    # reshape a boolean vector.
+    touched = touched_ref[0].astype(jnp.float32)[..., None] > 0
+    new_ent = jnp.where(touched, merged, ent)
 
     # Straggler/fault gating: an excluded client's upload is a no-op.
-    inc = inc_ref[0] > 0
+    inc = inc_ref[k] > 0                                      # SMEM scalar
     ent_s[...] = jnp.where(inc, new_ent, ent)
     phi_s[...] = jnp.where(inc, phi_g + phi_l, phi_g)
 
@@ -105,37 +107,40 @@ def cache_merge_round(entries: jax.Array, phi_global: jax.Array,
     Ip = -(-I // I_TILE) * I_TILE
     pad_i = Ip - I
     ep = jnp.pad(entries, ((0, 0), (0, pad_i), (0, 0)))
-    pp = jnp.pad(phi_global.astype(jnp.float32), (0, pad_i))
+    # Φ and φ ride as rows, (1, I) and (K, 1, I): their blocks' last two
+    # dims are then (1, I_TILE), a shape the TPU accepts for any K; the
+    # include mask is read as SMEM scalars.
+    pp = jnp.pad(phi_global.astype(jnp.float32), (0, pad_i))[None, :]
     up_ = jnp.pad(u, ((0, 0), (0, 0), (0, pad_i), (0, 0)))
-    phip = jnp.pad(phi, ((0, 0), (0, pad_i)))
+    phip = jnp.pad(phi, ((0, 0), (0, pad_i)))[:, None, :]
     tp = jnp.pad(u_touched.astype(jnp.int32), ((0, 0), (0, 0), (0, pad_i)))
     incp = include.astype(jnp.int32)
     n_i = Ip // I_TILE
 
     out_shapes = (
         jax.ShapeDtypeStruct((L, Ip, d), jnp.float32),   # merged entries
-        jax.ShapeDtypeStruct((Ip,), jnp.float32),        # merged Φ
+        jax.ShapeDtypeStruct((1, Ip), jnp.float32),      # merged Φ
     )
     ent, phi_out = pl.pallas_call(
         functools.partial(_kernel_merge, gamma=gamma, num_clients=K),
         grid=(n_i, K),
         in_specs=[
             pl.BlockSpec((L, I_TILE, d), lambda i, k: (0, i, 0)),
-            pl.BlockSpec((I_TILE,), lambda i, k: (i,)),
+            pl.BlockSpec((1, I_TILE), lambda i, k: (0, i)),
             pl.BlockSpec((1, L, I_TILE, d), lambda i, k: (k, 0, i, 0)),
-            pl.BlockSpec((1, I_TILE), lambda i, k: (k, i)),
+            pl.BlockSpec((1, 1, I_TILE), lambda i, k: (k, 0, i)),
             pl.BlockSpec((1, L, I_TILE), lambda i, k: (k, 0, i)),
-            pl.BlockSpec((1,), lambda i, k: (k,)),
+            pl.BlockSpec(memory_space=pltpu.SMEM),
         ],
         out_specs=(
             pl.BlockSpec((L, I_TILE, d), lambda i, k: (0, i, 0)),
-            pl.BlockSpec((I_TILE,), lambda i, k: (i,)),
+            pl.BlockSpec((1, I_TILE), lambda i, k: (0, i)),
         ),
         scratch_shapes=[
             pltpu.VMEM((L, I_TILE, d), jnp.float32),     # running entries
-            pltpu.VMEM((I_TILE,), jnp.float32),          # running Φ
+            pltpu.VMEM((1, I_TILE), jnp.float32),        # running Φ
         ],
         out_shape=out_shapes,
         interpret=interpret,
     )(ep, pp, up_, phip, tp, incp)
-    return ent[:, :I, :], phi_out[:I]
+    return ent[:, :I, :], phi_out[0, :I]

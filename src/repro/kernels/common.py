@@ -74,21 +74,22 @@ def lookup_single_pass_vmem_bytes(num_layers: int, num_classes: int,
 def lookup_tiled_vmem_bytes(num_layers: int, i_block: int, sem_dim: int,
                             b_tile: int = B_TILE,
                             entry_dtype: str = "float32") -> int:
-    """Resident bytes of the class-tiled lookup at one grid step: one
-    ``(L, i_block, d)`` entries slab (+ scale plane when quantized), one tile
-    of taps, the per-block Eq.-1 accumulator, and the ``(B_TILE, L)`` running
-    top-2/argmax scratch.
+    """Resident bytes of the class-tiled lookup at one grid step: the two
+    ``(L, i_block, d)`` entries slab slots (+ scale planes when quantized)
+    of the kernel's double-buffered DMA, the double-buffered tile of taps,
+    the per-block Eq.-1 accumulator, the running top-2/argmax state, and the
+    double-buffered output tiles.
 
-    The kernel double-buffers the slab DMA through a two-slot scratch; the
-    second slot occupies the same pipeline headroom ``VMEM_FRACTION`` always
-    reserved for Pallas' automatic input double-buffering, so the plannable
-    working set stays one slab.
+    Both slab slots count: the TPU compiler allocates them as scoped VMEM
+    next to the pipeline's own buffers, so a plan that booked one slot
+    overflowed the chip's 16 MiB scoped limit at serving widths.
     """
-    entries = num_layers * i_block * entry_row_bytes(sem_dim, entry_dtype)
-    taps = b_tile * num_layers * sem_dim * _F32
+    entries = 2 * num_layers * i_block * entry_row_bytes(sem_dim,
+                                                          entry_dtype)
+    taps = 2 * b_tile * num_layers * sem_dim * _F32
     acc = 2 * b_tile * i_block * _F32          # a_prev + candidate
     top2 = 3 * b_tile * num_layers * _F32
-    outs = b_tile * (2 * num_layers + 1) * _F32
+    outs = 2 * b_tile * (2 * num_layers + 1) * _F32
     return entries + taps + acc + top2 + outs
 
 

@@ -25,6 +25,7 @@ from repro.core import AcaPolicy, CacheConfig, CocaCluster, SimulationConfig, \
 from repro.data import (PoissonArrivals, RequestStream, StreamConfig,
                         Stationary, longtail_prior, make_client_context,
                         make_tap_model, perturb_tap_model, synthesize_taps)
+from repro.launch.compile_cache import use_compile_cache
 from repro.serving.batching import BatchingConfig
 from repro.serving.loop import ServeLoopConfig, ServingSession, \
     throughput_gain
@@ -46,6 +47,7 @@ def main() -> None:
     ap.add_argument("--target", type=float, default=0.9,
                     help="SLO attainment target for the Θ controller")
     args = ap.parse_args()
+    use_compile_cache()
 
     model_cfg = get_config(args.arch, smoke=args.smoke)
     n_taps = max(len(model_cfg.tap_layers()), 4)

@@ -417,3 +417,33 @@ def test_ssd_scan_state_continuity():
     y_big = ops.ssd_scan(x, dt, a, Bm, Cm, chunk=64)
     np.testing.assert_allclose(np.asarray(y_small), np.asarray(y_big),
                                rtol=1e-4, atol=1e-4)
+
+
+@pytest.mark.parametrize("impl", ["fused_single", "fused_tiled"])
+@pytest.mark.parametrize("quantized", [False, True])
+def test_stacked_tables_lookup_matches_per_table(impl, quantized):
+    """K stacked client tables in one launch (the round engine's batched
+    lookup) == K separate lookups: the table axis only selects operands."""
+    from repro.core.semantic_cache import (CacheConfig, CacheTable,
+                                           l2_normalize, lookup_all_layers,
+                                           quantize_table)
+    K, B, I, L, d = 3, 20, 300, 4, 16
+    key = jax.random.PRNGKey(41)
+    tables = []
+    for c in range(K):
+        kc = jax.random.fold_in(key, c)
+        t = CacheTable(
+            l2_normalize(jnp.abs(jax.random.normal(kc, (L, I, d)))),
+            jax.random.bernoulli(jax.random.fold_in(kc, 1), 0.7, (I,)),
+            jax.random.bernoulli(jax.random.fold_in(kc, 2), 0.8, (L,)))
+        tables.append(quantize_table(t) if quantized else t)
+    stacked = jax.tree_util.tree_map(lambda *x: jnp.stack(x), *tables)
+    sems = jnp.abs(jax.random.normal(jax.random.fold_in(key, 9), (K, B, L, d)))
+    cfg = CacheConfig(num_classes=I, num_layers=L, sem_dim=d, theta=0.02)
+    out = lookup_all_layers(stacked, sems, cfg, impl=impl)
+    assert out.scores.shape == (K, B, L)
+    for c in range(K):
+        one = lookup_all_layers(tables[c], sems[c], cfg, impl=impl)
+        for f in ("hit", "exit_layer", "pred", "scores"):
+            np.testing.assert_array_equal(np.asarray(getattr(out, f))[c],
+                                          np.asarray(getattr(one, f)))

@@ -131,3 +131,72 @@ np.testing.assert_allclose(np.asarray(e_sh), np.asarray(e_ref),
 np.testing.assert_allclose(np.asarray(phi_sh), np.asarray(phi_ref))
 print("PROFILE SHARDED OK")
 """, devices=4)
+
+
+def test_fused_merge_class_sharded_rounds_match_one_device():
+    """The Pallas merge under a class-sharded ServerState runs per class
+    shard (``shard_map`` over the class axis): CocaCluster rounds on a
+    4-device mesh match the same rounds on one device, the table stays
+    split across rounds, and the compiled round carries no all-gather of
+    the (L, I, d) table.  Kernels run interpreted on the virtual CPU
+    devices; chip_smoke.py --chips 4 makes the same comparison on chips."""
+    from tests.conftest import run_multidevice
+    run_multidevice("""
+import numpy as np, jax, jax.numpy as jnp
+from repro.core import (CacheConfig, CocaCluster, FrameBatch,
+                        SimulationConfig, calibrate)
+from repro.core import engine as engine_mod
+from repro.core.server import ServerConfig
+from repro.data import (StreamConfig, make_tap_model, perturb_tap_model,
+                        synthesize_taps)
+
+I, L, D, F, K = 64, 4, 16, 24, 3
+scfg = StreamConfig(num_classes=I, num_layers=L, sem_dim=D)
+tm = make_tap_model(jax.random.PRNGKey(0), scfg)
+tm_cal = perturb_tap_model(jax.random.PRNGKey(42), tm, 0.35)
+cm = calibrate(np.full(L + 1, 5.0), np.full(L, D), head_cost=1.0)
+shared = np.tile(np.arange(I), 4)
+cal = synthesize_taps(jax.random.PRNGKey(1), tm_cal, jnp.asarray(shared), scfg)
+sim = SimulationConfig(
+    cache=CacheConfig(num_classes=I, num_layers=L, sem_dim=D, theta=0.1),
+    server=ServerConfig(merge_impl="fused"), round_frames=F,
+    mem_budget=16_000.0)
+rng = np.random.default_rng(np.random.SeedSequence((3,)))
+rounds = [[rng.integers(0, I, F) for _ in range(K)] for _ in range(2)]
+
+compiled = []
+orig = engine_mod.round_step
+def spy(*args, **kw):
+    compiled.append(orig.lower(*args, **kw).compile().as_text())
+    return orig(*args, **kw)
+engine_mod.round_step = spy
+
+def run(mesh):
+    cl = CocaCluster(sim, cm, num_clients=K, mesh=mesh)
+    cl.bootstrap(jax.random.PRNGKey(0), cal, shared)
+    metrics = []
+    for r, labs in enumerate(rounds):
+        metrics.append(cl.step([FrameBatch(*synthesize_taps(
+            jax.random.PRNGKey(100 * r + k), tm, jnp.asarray(lab), scfg),
+            labels=lab) for k, lab in enumerate(labs)]))
+    return cl, metrics
+
+one, m1 = run(None)
+mesh = jax.make_mesh((4,), ("data",),
+                     axis_types=(jax.sharding.AxisType.Auto,))
+sh, m4 = run(mesh)
+assert "data" in str(sh.server.entries.sharding.spec), sh.server.entries.sharding
+for a, b in zip(m1, m4):
+    for f in ("pred", "hit", "exit_layer"):
+        np.testing.assert_array_equal(getattr(a, f), getattr(b, f))
+np.testing.assert_array_equal(np.asarray(sh.server.phi_global),
+                              np.asarray(one.server.phi_global))
+np.testing.assert_allclose(np.asarray(sh.server.entries),
+                           np.asarray(one.server.entries), rtol=1e-6,
+                           atol=1e-6)
+table = f"f32[{L},{I},{D}]"
+gathers = [ln for ln in compiled[-1].splitlines()
+           if "all-gather" in ln and table in ln]
+assert not gathers, gathers
+print("FUSED MERGE SHARDED PARITY OK")
+""", devices=4)

@@ -34,6 +34,7 @@ import functools
 import inspect
 
 import jax
+import jax.numpy as jnp
 
 try:
     import pytest
@@ -133,7 +134,8 @@ def sentinel_round_step():
     from repro.core import engine as engine_mod
     raw = engine_mod.round_step.__wrapped__
     return counted_jit(raw, static_argnames=(
-        "cfg", "absorb", "scfg", "cm", "global_updates", "deadline"))
+        "cfg", "absorb", "scfg", "cm", "global_updates", "deadline",
+        "mesh"))
 
 
 def sentinel_batched_lookup():
@@ -159,8 +161,15 @@ def sentinel_tiled_lookup():
 # ---------------------------------------------------------------------------
 
 
-def _checkify_errors():
+def _checkify_errors(kernel: bool):
     from jax.experimental import checkify
+    if kernel:
+        # checkify's out-of-bounds grid check over a pallas_call (JAX 0.9)
+        # seeds its loop with float32 blocks and fails to trace for a
+        # kernel with int32 outputs, as the fused lookups have.  Through a
+        # kernel the index checks are therefore the explicit output-range
+        # checks in _checked_lookup_jit (user checks).
+        return checkify.float_checks | checkify.user_checks
     return checkify.float_checks | checkify.index_checks
 
 
@@ -170,19 +179,32 @@ def _checked_lookup_jit(impl: str):
 
     from repro.core.semantic_cache import lookup_all_layers
 
-    def fn(table, sems, cfg):
-        return lookup_all_layers(table, sems, cfg, impl=impl)
+    kernel = impl != "ref" and (impl != "auto"
+                                or jax.default_backend() == "tpu")
 
-    return jax.jit(checkify.checkify(fn, errors=_checkify_errors()),
+    def fn(table, sems, cfg):
+        out = lookup_all_layers(table, sems, cfg, impl=impl)
+        if kernel:
+            L, I = cfg.num_layers, cfg.num_classes
+            checkify.check(jnp.all((out.exit_layer >= 0)
+                                   & (out.exit_layer <= L)),
+                           "lookup exit layer out of range [0, L]")
+            checkify.check(jnp.all((out.pred >= 0) & (out.pred < I)),
+                           "lookup prediction out of range [0, I)")
+        return out
+
+    return jax.jit(checkify.checkify(fn, errors=_checkify_errors(kernel)),
                    static_argnames=("cfg",))
 
 
 def checked_lookup(table, sems, cfg, *, impl: str = "fused"):
     """The fused cache lookup under checkify NaN/OOB checks.
 
-    Raises ``JaxRuntimeError`` on the first NaN/inf/out-of-bounds produced
-    anywhere inside the lookup (Pallas kernels run in interpret mode on
-    CPU, where checkify sees through them).  Returns the usual
+    Raises ``JaxRuntimeError`` on the first NaN/inf produced anywhere
+    inside the lookup (Pallas kernels run in interpret mode on CPU, where
+    checkify sees through them), and on an out-of-bounds index: checkify's
+    own index checks on the reference path, and range checks on the
+    exit layers and predictions a kernel returns.  Returns the usual
     ``LookupResult``.
     """
     err, out = _checked_lookup_jit(impl)(table, sems, cfg=cfg)
