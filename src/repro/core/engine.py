@@ -68,6 +68,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro import obs
 from repro.core import aca as aca_mod
 from repro.core.adaptive_thresholds import ThresholdTarget, calibrate_absorption
 from repro.core.client import (AbsorptionConfig, ClientState, init_client,
@@ -927,12 +928,15 @@ class CocaCluster:
             raise RuntimeError("client count unknown: pass num_clients= at "
                                "construction or step() once first")
         entries = self._gathered_entries()
-        return [allocate_subtable(
-                    entries,
-                    jnp.asarray(self._policy.allocate(
-                        self.allocation_context(k))),
-                    entry_dtype=self.sim.cache.entry_dtype)
-                for k in self.active_clients]
+        tables = []
+        for k in self.active_clients:
+            with obs.span("coca.round.aca", client=int(k)):
+                x = self._policy.allocate(self.allocation_context(k))
+            with obs.span("coca.round.cut", client=int(k)):
+                tables.append(allocate_subtable(
+                    entries, jnp.asarray(x),
+                    entry_dtype=self.sim.cache.entry_dtype))
+        return tables
 
     # -------------------------------------------------- serving-loop hooks
     def set_theta(self, theta: float) -> None:
@@ -1122,6 +1126,11 @@ class CocaCluster:
             raise ValueError("step() needs at least one frame batch")
         frames = [fb if isinstance(fb, FrameBatch) else FrameBatch(*fb)
                   for fb in frames]
+        with obs.span("coca.round", round=self._round):
+            return self._step(frames, tables, upload_mask)
+
+    def _step(self, frames: list[FrameBatch], tables: Sequence | None,
+              upload_mask: Sequence | None) -> RoundMetrics:
         self._ensure_clients(len(frames))
         if tables is not None and len(tables) != len(frames):
             raise ValueError(f"tables= has {len(tables)} entries for "
@@ -1181,10 +1190,12 @@ class CocaCluster:
         sim = self.sim
         act = np.flatnonzero(self._active)               # ascending slots
         all_active = len(act) == self._K
-        tables = _stack_tables(list(tables_in) if tables_in is not None
-                               else self.allocate_tables())
-        sems = jnp.stack([jnp.asarray(fb.sems) for fb in frames])
-        logits = jnp.stack([jnp.asarray(fb.logits) for fb in frames])
+        tables = (list(tables_in) if tables_in is not None
+                  else self.allocate_tables())
+        with obs.span("coca.round.stack"):
+            tables = _stack_tables(tables)
+            sems = jnp.stack([jnp.asarray(fb.sems) for fb in frames])
+            logits = jnp.stack([jnp.asarray(fb.logits) for fb in frames])
 
         # Churn masking: only the active slots enter the fused round_step —
         # inactive clients contribute no frames and no Eq.-4/5 upload, and
@@ -1194,12 +1205,13 @@ class CocaCluster:
                      jax.tree_util.tree_map(lambda x: x[idx], self._states))
         mask = (None if upload_mask is None
                 else jnp.asarray(np.asarray(upload_mask, bool)))
-        new_states, self._server, m = round_step(
-            states_in, tables, sems, logits, self._server,
-            cfg=sim.cache, absorb=sim.absorb, scfg=sim.server, cm=self._cm,
-            global_updates=sim.global_updates,
-            deadline=sim.straggler_deadline, upload_mask=mask,
-            mesh=self._mesh)
+        with obs.span("coca.round.dispatch"):
+            new_states, self._server, m = round_step(
+                states_in, tables, sems, logits, self._server,
+                cfg=sim.cache, absorb=sim.absorb, scfg=sim.server,
+                cm=self._cm, global_updates=sim.global_updates,
+                deadline=sim.straggler_deadline, upload_mask=mask,
+                mesh=self._mesh)
         self._states = (new_states if all_active else
                         jax.tree_util.tree_map(
                             lambda full, new: full.at[idx].set(new),
@@ -1209,9 +1221,10 @@ class CocaCluster:
 
         # The single device→host transfer of the round: metrics ride along
         # with the status vectors the next round's allocation needs.
-        m, self._host_phi, self._host_r, self._host_tau = jax.device_get(
-            (m, self._server.phi_global, self._server.r_est,
-             self._states.tau))
+        with obs.span("coca.round.sync"):
+            m, self._host_phi, self._host_r, self._host_tau = jax.device_get(
+                (m, self._server.phi_global, self._server.r_est,
+                 self._states.tau))
         F = frames[0].num_frames
         return RoundMetrics(
             pred=np.asarray(m["pred"]).ravel().astype(np.int32),

@@ -50,6 +50,7 @@ from __future__ import annotations
 
 import dataclasses
 import heapq
+import time
 from functools import partial
 from typing import Callable, NamedTuple
 
@@ -57,6 +58,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro import obs
 from repro.core.semantic_cache import CacheTable, lookup_all_layers
 from repro.data.scenarios import RequestStream
 from repro.serving.batching import BatchingConfig
@@ -300,28 +302,32 @@ class ServingSession:
         lookup on the live table.  Returns (blocks, hit, pred)."""
         nb = self.cfg.batching.num_blocks
         n = len(labels)
-        sems, logits = self.tap_fn(window, labels)
+        with obs.span("coca.tick.backbone"):
+            sems, logits = self.tap_fn(window, labels)
         if not (self.use_cache and table is not None):
             # the no-cache tick's one bundled transfer (tap_fn may hand back
             # device arrays); explicit, so the transfer guard stays quiet
-            logits = jax.device_get(logits)  # cocalint: disable=CL202
+            with obs.span("coca.tick.sync"):
+                logits = jax.device_get(logits)  # cocalint: disable=CL202
             model_pred = np.argmax(logits, axis=1).astype(np.int32)
             return (np.full(n, nb, np.int64), np.zeros(n, bool), model_pred)
-        sems = jnp.asarray(sems)         # explicit h2d — guard-legal
-        pad = self.cfg.batching.max_slots - n
-        if pad > 0:                      # fixed shape -> one compiled trace
-            # lax.slice_in_dim, not _pad_block[:pad]: eager jnp basic
-            # indexing materialises its index scalars host-side (an
-            # implicit transfer); the lax slice is fully static.
-            sems = jnp.concatenate(
-                [sems, jax.lax.slice_in_dim(self._pad_block, 0, pad)])
-        look = _batched_lookup(table, sems, self.cluster.sim.cache)
+        with obs.span("coca.tick.lookup"):
+            sems = jnp.asarray(sems)     # explicit h2d — guard-legal
+            pad = self.cfg.batching.max_slots - n
+            if pad > 0:                  # fixed shape -> one compiled trace
+                # lax.slice_in_dim, not _pad_block[:pad]: eager jnp basic
+                # indexing materialises its index scalars host-side (an
+                # implicit transfer); the lax slice is fully static.
+                sems = jnp.concatenate(
+                    [sems, jax.lax.slice_in_dim(self._pad_block, 0, pad)])
+            look = _batched_lookup(table, sems, self.cluster.sim.cache)
         # The tick's ONE bundled device->host transfer: lookup verdicts and
         # model logits ride together (the serving-tick edition of PR 1's
         # one-device_get-per-round contract).
-        # cocalint: disable=CL202
-        hit, exit_layer, cache_pred, logits = jax.device_get(
-            (look.hit, look.exit_layer, look.pred, logits))
+        with obs.span("coca.tick.sync"):
+            # cocalint: disable=CL202
+            hit, exit_layer, cache_pred, logits = jax.device_get(
+                (look.hit, look.exit_layer, look.pred, logits))
         model_pred = np.argmax(logits, axis=1).astype(np.int32)
         hit = hit[:n]
         blocks = np.where(hit, np.minimum(exit_layer[:n] + 1, nb), nb)
@@ -360,6 +366,9 @@ class ServingSession:
         self._est = int(np.ceil(self._est_f))
         self._labels_by_rid: dict[int, int] = {}
         self._pred_by_rid: dict[int, int] = {}
+        # host clock at submit, popped at admission (the queue wait); a
+        # shed request's stays, as its label does
+        self._submit_ns: dict[int, int] = {}
         self._exit_blocks: list[int] = []
         self._reports: list[WindowReport] = []
         self._theta_trace: list[float] = []
@@ -394,6 +403,7 @@ class ServingSession:
         req = Request(rid=self._next_rid, arrival=arrival,
                       blocks_needed=self._est, deadline=float(deadline))
         self._labels_by_rid[req.rid] = int(label)
+        self._submit_ns[req.rid] = time.perf_counter_ns()
         self._next_rid += 1
         self._arrivals_total += 1
         sched.submit(req)
@@ -406,23 +416,34 @@ class ServingSession:
         session — the clock still advances, which is what keeps a fleet's
         replicas tick-synchronised through an outage."""
         sched = self._sched
-        placed = sched.admit()
-        if placed:
-            labs = np.asarray(
-                [self._labels_by_rid[r.rid] for _, r in placed], np.int32)
-            blocks, hit, pred = self._classify(window, labs, self._table)
-            for (slot, req), b, h, p in zip(placed, blocks, hit, pred):
-                sched.resolve(slot, int(b))
-                self._pred_by_rid[req.rid] = int(p)
-                self._exit_blocks.append(int(b))
-            self._observe(labs)
-            self._admitted_total += len(placed)
-            self._hits_total += int(hit.sum())
-        retired = sched.advance()
-        for req, _lat, _missed in retired:
-            lab = self._labels_by_rid[req.rid]
-            self._served_labeled += 1
-            self._correct += int(self._pred_by_rid[req.rid] == lab)
+        with obs.span("coca.tick", tick=int(sched.tick)):
+            with obs.span("coca.tick.admit"):
+                placed = sched.admit()
+            if placed:
+                now = time.perf_counter_ns()
+                wait_us = [(now - self._submit_ns.pop(r.rid)) // 1000
+                           for _, r in placed]
+                labs = np.asarray(
+                    [self._labels_by_rid[r.rid] for _, r in placed], np.int32)
+                with obs.span("coca.tick.classify", rows=len(placed),
+                              wait_us_sum=sum(wait_us),
+                              wait_us_max=max(wait_us)):
+                    blocks, hit, pred = self._classify(window, labs,
+                                                       self._table)
+            with obs.span("coca.tick.retire"):
+                if placed:
+                    for (slot, req), b, p in zip(placed, blocks, pred):
+                        sched.resolve(slot, int(b))
+                        self._pred_by_rid[req.rid] = int(p)
+                        self._exit_blocks.append(int(b))
+                    self._observe(labs)
+                    self._admitted_total += len(placed)
+                    self._hits_total += int(hit.sum())
+                retired = sched.advance()
+                for req, _lat, _missed in retired:
+                    lab = self._labels_by_rid[req.rid]
+                    self._served_labeled += 1
+                    self._correct += int(self._pred_by_rid[req.rid] == lab)
         return retired
 
     def begin_window(self, window: int) -> None:
@@ -513,6 +534,7 @@ class ServingSession:
         out = []
         while sched.queue:
             _, _, req = heapq.heappop(sched.queue)
+            self._submit_ns.pop(req.rid)
             out.append((req, self._labels_by_rid[req.rid]))
         for i, s in enumerate(sched.slots):
             if s is not None:
