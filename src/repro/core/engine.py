@@ -76,7 +76,8 @@ from repro.core.client import (AbsorptionConfig, ClientState, init_client,
 from repro.core.cost_model import CostModel, frame_latency
 from repro.core.metrics import FrameBatch, RoundMetrics
 from repro.core.semantic_cache import (CacheConfig, CacheTable,
-                                       allocate_subtable, lookup_all_layers)
+                                       allocate_subtable, allocate_subtables,
+                                       lookup_all_layers)
 from repro.core.server import (ServerConfig, ServerState, global_update,
                                init_server, merge_round,
                                profile_initial_cache)
@@ -498,13 +499,17 @@ class AdaptiveAbsorption:
 # --------------------------------------------------------------------------
 
 
+@jax.jit
+def _stack(trees: list):
+    """Stack a list of like pytrees leaf by leaf, in one program."""
+    return jax.tree_util.tree_map(lambda *leaves: jnp.stack(leaves), *trees)
+
+
 def _stack_tables(tables: list[CacheTable]) -> CacheTable:
-    entries, class_mask, layer_mask, scale = zip(*tables)
+    scale = [t.entry_scale for t in tables]
     if any((s is None) != (scale[0] is None) for s in scale):
         raise ValueError("cannot stack mixed float32/int8 cache tables")
-    return CacheTable(jnp.stack(entries), jnp.stack(class_mask),
-                      jnp.stack(layer_mask),
-                      None if scale[0] is None else jnp.stack(scale))
+    return _stack(list(tables))
 
 
 def _init_clients_batched(cfg: CacheConfig, num_clients: int) -> ClientState:
@@ -927,16 +932,20 @@ class CocaCluster:
         if self._K is None:
             raise RuntimeError("client count unknown: pass num_clients= at "
                                "construction or step() once first")
+        return self._allocate(stacked=False)
+
+    def _allocate(self, *, stacked: bool):
+        """The policy's allocation per active client, then all their cuts
+        in one call: the stacked table ``round_step`` takes, or its list."""
         entries = self._gathered_entries()
-        tables = []
+        xs = []
         for k in self.active_clients:
             with obs.span("coca.round.aca", client=int(k)):
-                x = self._policy.allocate(self.allocation_context(k))
-            with obs.span("coca.round.cut", client=int(k)):
-                tables.append(allocate_subtable(
-                    entries, jnp.asarray(x),
-                    entry_dtype=self.sim.cache.entry_dtype))
-        return tables
+                xs.append(self._policy.allocate(self.allocation_context(k)))
+        with obs.span("coca.round.cut", clients=len(xs)):
+            return allocate_subtables(
+                entries, jnp.asarray(np.stack(xs)),
+                entry_dtype=self.sim.cache.entry_dtype, stacked=stacked)
 
     # -------------------------------------------------- serving-loop hooks
     def set_theta(self, theta: float) -> None:
@@ -1190,12 +1199,17 @@ class CocaCluster:
         sim = self.sim
         act = np.flatnonzero(self._active)               # ascending slots
         all_active = len(act) == self._K
-        tables = (list(tables_in) if tables_in is not None
-                  else self.allocate_tables())
+        # Two preparation dispatches whatever K is: the one cut of every
+        # client's table (or the stack of the caller's tables), and the one
+        # stack of the taps and logits.
+        if tables_in is None:
+            tables = self._allocate(stacked=True)
         with obs.span("coca.round.stack"):
-            tables = _stack_tables(tables)
-            sems = jnp.stack([jnp.asarray(fb.sems) for fb in frames])
-            logits = jnp.stack([jnp.asarray(fb.logits) for fb in frames])
+            if tables_in is not None:
+                tables = _stack_tables(tables_in)
+            sems, logits = _stack([(jnp.asarray(fb.sems),
+                                    jnp.asarray(fb.logits))
+                                   for fb in frames])
 
         # Churn masking: only the active slots enter the fused round_step —
         # inactive clients contribute no frames and no Eq.-4/5 upload, and

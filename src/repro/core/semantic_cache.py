@@ -385,13 +385,16 @@ _F32_ZERO = None
 
 def _f32_zero() -> jax.Array:
     """Lazily-cached device-resident float32 zero.  ``allocate_subtable``
-    runs *eagerly* at every round start; a literal ``0.0`` there would
-    re-materialise a host scalar each round — an implicit transfer the
-    runtime sanitizer's guard forbids.  One explicit device_put, reused."""
+    runs *eagerly* for a single cut; a literal ``0.0`` there would
+    re-materialise a host scalar each call — an implicit transfer the
+    runtime sanitizer's guard forbids.  One explicit device_put, reused;
+    made eagerly even when first asked for inside a trace
+    (:func:`allocate_subtables`), so no tracer is cached."""
     global _F32_ZERO
     if _F32_ZERO is None:
         import numpy as np
-        _F32_ZERO = jax.device_put(np.zeros((), np.float32))
+        with jax.ensure_compile_time_eval():
+            _F32_ZERO = jax.device_put(np.zeros((), np.float32))
     return _F32_ZERO
 
 
@@ -423,3 +426,23 @@ def allocate_subtable(global_entries: jax.Array, x: jax.Array,
         class_mask=class_mask,
         layer_mask=layer_mask,
     )
+
+
+@partial(jax.jit, static_argnames=("entry_dtype", "stacked"))
+def allocate_subtables(global_entries: jax.Array, xs: jax.Array,
+                       *, entry_dtype: str = "float32",
+                       stacked: bool = True):
+    """K clients' cuts in one program: :func:`allocate_subtable` vmapped
+    over the (K, L, I) allocation matrices ``xs``.
+
+    Returns the stacked :class:`CacheTable` (K, L, I, d) that
+    :func:`repro.core.engine.round_step` takes, or with ``stacked=False``
+    the list of the K tables.  Each cut is bitwise the one
+    :func:`allocate_subtable` makes alone, in one dispatch whatever K is.
+    """
+    tables = jax.vmap(lambda x: allocate_subtable(
+        global_entries, x, entry_dtype=entry_dtype))(xs)
+    if stacked:
+        return tables
+    return [jax.tree_util.tree_map(lambda a, k=k: a[k], tables)
+            for k in range(xs.shape[0])]

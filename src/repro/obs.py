@@ -31,16 +31,18 @@ Spans and their counters:
 * ``coca.round`` — the whole round; ``round``: the round index.
 * ``coca.round.aca`` — one client's allocation by the policy (numpy ACA);
   ``client``: its slot.
-* ``coca.round.cut`` — that client's table cut from the allocation;
-  ``client``: its slot.
-* ``coca.round.stack`` — stacking the clients' tables, taps and logits.
+* ``coca.round.cut`` — the one compiled cut of every active client's table
+  from the stacked allocations; ``clients``: their count K.
+* ``coca.round.stack`` — the one compiled stack of the clients' taps and
+  logits, and of their tables when the caller cut them.
 * ``coca.round.dispatch`` — the fused ``round_step`` call.
 * ``coca.round.sync`` — the round's one bundled ``jax.device_get``.
 
-``coca.round.aca`` and ``.cut`` come from ``allocate_tables``: they sit
-inside ``coca.round`` when ``step`` allocates, and just before it, outside
-any round, when the caller cuts the tables itself and passes them in
-(``step(tables=...)``, as the fault and topology layers do).
+``coca.round.aca`` and ``.cut`` come from the allocation, ``step``'s own
+or ``allocate_tables``: they sit inside ``coca.round`` when ``step``
+allocates, and just before it, outside any round, when the caller cuts
+the tables itself and passes them in (``step(tables=...)``, as the fault
+and topology layers do).
 ``coca.round.stack``, ``.dispatch`` and ``.sync`` belong to the vectorised
 round; the per-client reference path and the client-engine baselines sync
 once per client and carry none of them.

@@ -7,6 +7,7 @@ world — per-frame latencies included (aggregation is order-pinned in the
 canonical RoundMetrics record).
 """
 
+import dataclasses
 import warnings
 
 import jax
@@ -17,6 +18,7 @@ import pytest
 from repro import api
 from repro.core import calibrate, run_simulation, run_simulation_reference
 from repro.core.baselines import FoggyCache
+from repro.core.semantic_cache import allocate_subtable
 
 I, L, D, F, K, R = 10, 4, 16, 24, 3, 3
 
@@ -241,6 +243,64 @@ def test_adaptive_absorption_recalibrates_thresholds():
     assert after.beta == before.beta            # decay is not the target
     assert np.isfinite(res.avg_latency)
     assert res.accuracy > 0.5
+
+# ---------------------------------------------------------------------------
+# the round's own batched cut against the caller's per-client tables
+# ---------------------------------------------------------------------------
+
+def _with_entry_dtype(sim, dtype):
+    return dataclasses.replace(
+        sim, cache=dataclasses.replace(sim.cache, entry_dtype=dtype))
+
+
+def _assert_trees_equal(a, b):
+    la, lb = jax.tree_util.tree_leaves(a), jax.tree_util.tree_leaves(b)
+    assert len(la) == len(lb)
+    for x, y in zip(la, lb):
+        np.testing.assert_array_equal(np.asarray(x), np.asarray(y))
+
+
+@pytest.mark.parametrize("dtype", ["float32", "int8"])
+def test_step_own_cut_matches_caller_cut_bit_for_bit(dtype):
+    """Rounds that cut their own tables (one batched cut) serve and merge
+    bit for bit as rounds handed ``allocate_tables()``'s list."""
+    sim, cm, tap_shared, shared, tap_fn, labels = _world()
+    sim = _with_entry_dtype(sim, dtype)
+    server = api.bootstrap_server(jax.random.PRNGKey(0), sim, tap_shared,
+                                  shared, cm)
+    own = api.CocaCluster(sim, cm, server=server, num_clients=K)
+    caller = api.CocaCluster(sim, cm, server=server, num_clients=K)
+    for r in range(R):
+        m1 = own.step(_batches(tap_fn, labels, r))
+        m2 = caller.step(_batches(tap_fn, labels, r),
+                         tables=caller.allocate_tables())
+        for f in ("pred", "hit", "exit_layer", "latency"):
+            np.testing.assert_array_equal(getattr(m1, f), getattr(m2, f))
+    assert own.result().hit_ratio > 0
+    _assert_trees_equal(own.server, caller.server)
+
+
+@pytest.mark.parametrize("dtype", ["float32", "int8"])
+def test_active_subset_cut_matches_separate_cuts(dtype):
+    """After ``remove_client`` the batched cut covers only the active slots,
+    each table bitwise the one ``allocate_subtable`` cuts from that
+    client's own allocation."""
+    sim, cm, tap_shared, shared, tap_fn, labels = _world()
+    sim = _with_entry_dtype(sim, dtype)
+    cluster = api.CocaCluster(sim, cm, policy=api.AcaPolicy(), num_clients=5)
+    cluster.bootstrap(jax.random.PRNGKey(0), tap_shared, shared)
+    cluster.step([api.FrameBatch(*tap_fn(0, k, labels[0, k % K]),
+                                 labels=labels[0, k % K]) for k in range(5)])
+    cluster.remove_client(1)
+    cluster.remove_client(3)
+    tables = cluster.allocate_tables()
+    assert cluster.active_clients == [0, 2, 4] and len(tables) == 3
+    entries = cluster.gathered_entries()
+    for k, t in zip(cluster.active_clients, tables):
+        x = api.AcaPolicy().allocate(cluster.allocation_context(k))
+        alone = allocate_subtable(entries, jnp.asarray(x), entry_dtype=dtype)
+        assert (t.entry_scale is None) == (dtype == "float32")
+        _assert_trees_equal(alone, t)
 
 
 # ---------------------------------------------------------------------------

@@ -194,22 +194,24 @@ def test_round_spans_nest_and_count(world, tmp_path):
     rnds = named(spans, "coca.round")
     assert [s[3]["round"] for s in rnds] == list(range(ROUNDS))
     for r in rnds:
-        for child in ("coca.round.aca", "coca.round.cut"):
-            got = children(r, spans, child)
-            assert [s[3]["client"] for s in got] == list(range(K)), child
+        got = children(r, spans, "coca.round.aca")
+        assert [s[3]["client"] for s in got] == list(range(K))
+        # one cut of all K tables per round
+        assert [s[3]["clients"]
+                for s in children(r, spans, "coca.round.cut")] == [K]
         for child in ("coca.round.stack", "coca.round.dispatch",
                       "coca.round.sync"):
             assert len(children(r, spans, child)) == 1, child
     for name in ROUND_SPANS - {"coca.round"}:
         assert len(named(spans, name)) == ROUNDS * (
-            K if name in ("coca.round.aca", "coca.round.cut") else 1)
+            K if name == "coca.round.aca" else 1)
 
 
 def test_round_tables_cut_by_the_caller(world, tmp_path):
     """Tables the caller cuts before ``step(tables=...)`` give their ACA
-    and cut spans just before the round, outside it, once per client; the
-    round itself holds none, and its results match the round that cuts its
-    own tables."""
+    spans (one per client) and their one cut span just before the round,
+    outside it; the round itself holds none, and its results match the
+    round that cuts its own tables."""
     with jax.profiler.trace(str(tmp_path)):
         outside = rounds(world, cut_first=True)
     spans = read_spans(str(tmp_path))
@@ -218,11 +220,13 @@ def test_round_tables_cut_by_the_caller(world, tmp_path):
     assert len(rnds) == ROUNDS
     prev_end = -np.inf
     for r in rnds:
-        for child in ("coca.round.aca", "coca.round.cut"):
+        for child, counter, want in (("coca.round.aca", "client",
+                                      list(range(K))),
+                                     ("coca.round.cut", "clients", [K])):
             assert children(r, spans, child) == []
             got = [s for s in named(spans, child)
                    if prev_end <= s[1] and s[2] <= r[1]]
-            assert [s[3]["client"] for s in got] == list(range(K)), child
+            assert [s[3][counter] for s in got] == want, child
         prev_end = r[2]
     for a, b in zip(outside, rounds(world)):
         for x, y in zip(a, b):

@@ -33,7 +33,8 @@ from hypothesis import given, settings
 import hypothesis.strategies as st
 
 from repro.core.semantic_cache import (CacheConfig, CacheTable,
-                                       allocate_subtable, dequantize_entries,
+                                       allocate_subtable, allocate_subtables,
+                                       dequantize_entries,
                                        dequantize_table, l2_normalize,
                                        lookup_all_layers,
                                        lookup_all_layers_ref,
@@ -224,6 +225,35 @@ def test_stack_tables_rejects_mixed_dtypes():
     assert stacked.quantized and stacked.entries.shape[0] == 2
     with pytest.raises(ValueError, match="mixed"):
         _stack_tables([fp, qt])
+
+
+def _assert_tables_equal(a, b):
+    assert (a.entry_scale is None) == (b.entry_scale is None)
+    for x, y in zip(a, b):
+        if x is not None:
+            assert x.dtype == y.dtype and x.shape == y.shape
+            np.testing.assert_array_equal(np.asarray(x), np.asarray(y))
+
+
+@pytest.mark.parametrize("num_clients", [1, 5])
+@pytest.mark.parametrize("dtype", ["float32", "int8"])
+def test_batched_cut_matches_separate_cuts(dtype, num_clients):
+    """One vmapped cut of K allocations is, bit for bit, the K cuts that
+    allocate_subtable makes alone, whether stacked or listed."""
+    entries = l2_normalize(jax.random.normal(KEY, (3, 16, 8)))
+    xs = np.random.default_rng(num_clients).random((num_clients, 3, 16)) < .4
+    xs[0, 1] = False                       # a layer left out of one cut
+    stacked = allocate_subtables(entries, jnp.asarray(xs), entry_dtype=dtype)
+    listed = allocate_subtables(entries, jnp.asarray(xs), entry_dtype=dtype,
+                                stacked=False)
+    assert stacked.entries.shape == (num_clients, 3, 16, 8)
+    assert len(listed) == num_clients
+    for k in range(num_clients):
+        alone = allocate_subtable(entries, jnp.asarray(xs[k]),
+                                  entry_dtype=dtype)
+        _assert_tables_equal(alone, listed[k])
+        _assert_tables_equal(
+            alone, jax.tree_util.tree_map(lambda a, k=k: a[k], stacked))
 
 
 def test_cluster_runs_quantized_end_to_end():

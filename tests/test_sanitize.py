@@ -21,7 +21,8 @@ from repro.serving.batching import BatchingConfig
 from repro.serving.loop import ServeLoopConfig, ServingSession
 from tools.cocalint.sanitize import (checked_lookup, no_implicit_transfers,
                                      sentinel_batched_lookup,
-                                     sentinel_round_step)
+                                     sentinel_round_stack,
+                                     sentinel_round_step, sentinel_table_cut)
 
 I, L, D, F = 12, 4, 16, 40
 NB = L + 1
@@ -111,6 +112,20 @@ def test_engine_rounds_run_under_transfer_guard(world):
     assert len(m.pred) == 2 * F
 
 
+def test_caller_cut_rounds_run_under_transfer_guard(world):
+    """Rounds handed the caller's tables (the fault and topology layers'
+    ``step(tables=allocate_tables())``) cut and stack them with no implicit
+    transfer either."""
+    make_cluster, taps_for = world
+    cluster = make_cluster(num_clients=2)
+    rounds = [_round_batches(taps_for, 2, r) for r in range(3)]
+    cluster.step(rounds[0], tables=cluster.allocate_tables())
+    with no_implicit_transfers():
+        for batches in rounds[1:]:
+            m = cluster.step(batches, tables=cluster.allocate_tables())
+    assert len(m.pred) == 2 * F
+
+
 def test_serving_session_runs_under_transfer_guard(world):
     """A full multi-window online session — admission, the jitted tick
     lookup, Θ control, between-window re-allocation — with implicit
@@ -178,6 +193,27 @@ def test_round_step_retraces_only_on_new_active_count(world, monkeypatch):
     cluster.add_client()                # K: 2 -> 3, a genuinely new shape
     cluster.step(_round_batches(taps_for, 3, 1))
     cluster.step(_round_batches(taps_for, 3, 2))
+    assert counter.traces == 2
+    counter.assert_one_compile_per_shape()
+
+
+@pytest.mark.parametrize("name, sentinel", [
+    ("allocate_subtables", sentinel_table_cut),
+    ("_stack", sentinel_round_stack)])
+def test_round_preparation_compiles_once_per_active_count(world, monkeypatch,
+                                                          name, sentinel):
+    """The round's batched cut and its stack of taps and logits trace once
+    across same-shape rounds, and once more when ``add_client`` changes K."""
+    make_cluster, taps_for = world
+    counted, counter = sentinel()
+    monkeypatch.setattr(engine_mod, name, counted)
+    cluster = make_cluster(num_clients=2)
+    for r in range(3):
+        cluster.step(_round_batches(taps_for, 2, r))
+    assert counter.traces == 1
+    cluster.add_client()
+    for r in range(3, 5):
+        cluster.step(_round_batches(taps_for, 3, r))
     assert counter.traces == 2
     counter.assert_one_compile_per_shape()
 

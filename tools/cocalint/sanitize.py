@@ -17,7 +17,9 @@ Three sanitizers (docs/analysis.md has the full catalog):
   counter.distinct`` is the invariant "exactly one compile per distinct
   shape"; a retrace storm shows up as ``traces > distinct``.
   :func:`sentinel_round_step` / :func:`sentinel_batched_lookup` pre-wire
-  the two production hot paths for monkeypatching.
+  the two production hot paths for monkeypatching, and
+  :func:`sentinel_table_cut` / :func:`sentinel_round_stack` the round's
+  two preparation calls.
 
 * **Checkify debug mode** — :func:`checked_lookup` runs the fused Pallas
   cache lookup under ``checkify`` NaN/OOB checks; ``pytest
@@ -136,6 +138,21 @@ def sentinel_round_step():
     return counted_jit(raw, static_argnames=(
         "cfg", "absorb", "scfg", "cm", "global_updates", "deadline",
         "mesh"))
+
+
+def sentinel_table_cut():
+    """Counted drop-in for ``repro.core.engine.allocate_subtables`` — the
+    round's one batched table cut; monkeypatch the ``engine`` binding."""
+    from repro.core import semantic_cache
+    raw = semantic_cache.allocate_subtables.__wrapped__
+    return counted_jit(raw, static_argnames=("entry_dtype", "stacked"))
+
+
+def sentinel_round_stack():
+    """Counted drop-in for ``repro.core.engine._stack`` — the round's one
+    stack of the clients' taps and logits."""
+    from repro.core import engine as engine_mod
+    return counted_jit(engine_mod._stack.__wrapped__)
 
 
 def sentinel_batched_lookup():
