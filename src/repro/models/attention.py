@@ -80,9 +80,10 @@ def _qkv(p, x, cfg: ModelConfig, positions):
         q = q + bq.astype(x.dtype)
         k = k + p["bk"].astype(x.dtype)
         v = v + p["bv"].astype(x.dtype)
-    cos, sin = rope_freqs(cfg, positions)
-    q = apply_rope(q, cos, sin)
-    k = apply_rope(k, cos, sin)
+    if cfg.position == "rope":
+        cos, sin = rope_freqs(cfg, positions)
+        q = apply_rope(q, cos, sin)
+        k = apply_rope(k, cos, sin)
     return q, k, v
 
 
@@ -100,7 +101,10 @@ def _gqa_scores(q, k, cfg: ModelConfig):
     B, S, H, hd = q.shape
     g = H // cfg.kv_heads
     qg = q.reshape(B, S, cfg.kv_heads, g, hd)
-    return jnp.einsum("bskgd,btkd->bkgst", qg, k) / jnp.sqrt(hd).astype(q.dtype)
+    s = jnp.einsum("bskgd,btkd->bkgst", qg, k)
+    if cfg.attn_scale is not None:
+        return s * jnp.asarray(cfg.attn_scale, q.dtype)
+    return s / jnp.sqrt(hd).astype(q.dtype)
 
 
 def _gqa_out(scores, v, cfg: ModelConfig):
@@ -109,7 +113,8 @@ def _gqa_out(scores, v, cfg: ModelConfig):
     return out.reshape(B, S, -1, cfg.resolved_head_dim)
 
 
-def chunked_attention(q, k, v, positions, blocks, causal: bool):
+def chunked_attention(q, k, v, positions, blocks, causal: bool,
+                      scale: float | None = None):
     """Flash-semantics attention in pure XLA: scan over (q, kv) blocks with
     online softmax — the (S, T) score matrix never materialises in HBM.
     This is the compile-anywhere counterpart of kernels/flash_attention.py
@@ -123,7 +128,8 @@ def chunked_attention(q, k, v, positions, blocks, causal: bool):
     qb, kb = min(qb, S), min(kb, T)
     assert S % qb == 0 and T % kb == 0, (S, qb, T, kb)
     nq, nk = S // qb, T // kb
-    scale = 1.0 / (hd ** 0.5)
+    if scale is None:
+        scale = 1.0 / (hd ** 0.5)
     qs = jnp.moveaxis(q.reshape(B, nq, qb, H, hd), 1, 0)
     ks = jnp.moveaxis(k.reshape(B, nk, kb, H, hd), 1, 0)
     vs = jnp.moveaxis(v.reshape(B, nk, kb, H, hd), 1, 0)
@@ -172,7 +178,8 @@ def full_attention(p, x, cfg: ModelConfig, positions, *, causal: bool):
         rep = q.shape[2] // k.shape[2]
         kx = jnp.repeat(k, rep, axis=2) if rep > 1 else k
         vx = jnp.repeat(v, rep, axis=2) if rep > 1 else v
-        out = chunked_attention(q, kx, vx, positions, blocks, causal)
+        out = chunked_attention(q, kx, vx, positions, blocks, causal,
+                                cfg.attn_scale)
         return _out_proj(p, out.astype(x.dtype), cfg, x.dtype), (k, v)
     scores = _gqa_scores(q, k, cfg).astype(jnp.float32)   # (B, Hkv, G, S, T)
     if causal:

@@ -24,6 +24,24 @@ class MoEConfig:
     capacity_factor: float = 1.25
     router_aux_weight: float = 0.01   # load-balance loss weight
     moe_every: int = 1                # apply MoE FFN every k-th layer (jamba: 2)
+    d_shared: int = 0                 # shared SwiGLU expert width (0 = none)
+    # (first, count) of the experts this device holds: the router still
+    # scores all ``num_experts``, and dispatch is dropless over the held
+    # ones (expert parallelism's local part).  None = every expert, with
+    # capacity-based dispatch.
+    held: tuple[int, int] | None = None
+
+    def __post_init__(self):
+        if self.held is not None:
+            lo, n = (int(v) for v in self.held)
+            if lo < 0 or n <= 0 or lo + n > self.num_experts:
+                raise ValueError(f"held experts {self.held} outside "
+                                 f"0..{self.num_experts}")
+            object.__setattr__(self, "held", (lo, n))
+
+    @property
+    def num_held(self) -> int:
+        return self.num_experts if self.held is None else self.held[1]
 
 
 @dataclasses.dataclass(frozen=True)
@@ -33,6 +51,7 @@ class SSMConfig:
     expand: int = 2
     head_dim: int = 64
     chunk: int = 128                  # SSD chunk length
+    conv_bias: bool = False           # bias on the depthwise conv (granite)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -58,6 +77,10 @@ class ModelConfig:
     parallel_block: bool = False      # command-r: attn & FFN in parallel
     partial_rotary: float = 1.0       # glm4: 0.5 — RoPE on half the head dim
     rope_theta: float = 10_000.0
+    position: Literal["rope", "nope"] = "rope"   # nope: no rotary (granite)
+    attn_scale: float | None = None   # score scale; None = head_dim ** -0.5
+    embedding_multiplier: float = 1.0  # input embeddings x this (granite 12)
+    residual_multiplier: float = 1.0  # each residual branch x this (0.22)
     norm: Literal["rmsnorm", "layernorm"] = "rmsnorm"
     act: Literal["swiglu", "gelu"] = "swiglu"
     tie_embeddings: bool = False
@@ -79,6 +102,14 @@ class ModelConfig:
     #                                   needed when XLA cost_analysis must see
     #                                   every layer (roofline), since a while
     #                                   loop body is costed once, not ×G.
+
+    def __post_init__(self):
+        # nested groups given as dicts (a configuration read from JSON)
+        for name, kind in (("moe", MoEConfig), ("ssm", SSMConfig)):
+            v = getattr(self, name)
+            if isinstance(v, dict):
+                object.__setattr__(self, name, kind(**v))
+
     # long-context capability flag: quadratic-attention archs must skip
     # the 500k decode shape (DESIGN.md §4).
     @property

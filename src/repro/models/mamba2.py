@@ -51,6 +51,9 @@ def mamba_init(key, cfg: ModelConfig):
         "conv_x": truncated_normal(ks[5], (s.d_conv, d_in), 0.3),
         "conv_b": truncated_normal(ks[6], (s.d_conv, s.d_state), 0.3),
         "conv_c": truncated_normal(ks[7], (s.d_conv, s.d_state), 0.3),
+        **({"conv_x_bias": jnp.zeros((d_in,)),
+            "conv_b_bias": jnp.zeros((s.d_state,)),
+            "conv_c_bias": jnp.zeros((s.d_state,))} if s.conv_bias else {}),
         "a_log": jnp.log(jnp.linspace(1.0, 16.0, nheads)),
         "dt_bias": jnp.log(jnp.expm1(jnp.linspace(1e-3, 1e-1, nheads))),
         "d_skip": jnp.ones((nheads,)),
@@ -60,18 +63,23 @@ def mamba_init(key, cfg: ModelConfig):
     }
 
 
-def _conv_full(x, w):
-    """Depthwise causal conv over (B, S, ch) with taps (K, ch) + SiLU."""
+def _conv_full(x, w, b=None):
+    """Depthwise causal conv over (B, S, ch) with taps (K, ch) [+ bias] +
+    SiLU."""
     K = w.shape[0]
     pad = jnp.pad(x, ((0, 0), (K - 1, 0), (0, 0)))
     out = sum(pad[:, i:i + x.shape[1]] * w[i].astype(x.dtype) for i in range(K))
+    if b is not None:
+        out = out + b.astype(x.dtype)
     return jax.nn.silu(out)
 
 
-def _conv_step(x_t, w, state):
+def _conv_step(x_t, w, state, b=None):
     """Decode-step conv: state (B, K-1, ch), x_t (B, 1, ch)."""
     window = jnp.concatenate([state, x_t], axis=1)           # (B, K, ch)
     out = jnp.einsum("bkc,kc->bc", window, w.astype(x_t.dtype))[:, None]
+    if b is not None:
+        out = out + b.astype(x_t.dtype)
     return jax.nn.silu(out), window[:, 1:]
 
 
@@ -154,6 +162,9 @@ def mamba_fwd(p, u, cfg: ModelConfig, use_kernel: bool = False,
               return_state: bool = False):
     """Full-sequence SSD forward.  u (B, S, d_model) -> (B, S, d_model).
 
+    ``use_kernel`` runs the chunked scan as the Pallas ``ssd_scan`` kernel,
+    which has no backward pass; the default is the differentiable jnp
+    oracle.
     ``return_state=True`` additionally returns the :class:`SSMState` after the
     last position (prefill -> decode handoff).
     """
@@ -162,9 +173,9 @@ def mamba_fwd(p, u, cfg: ModelConfig, use_kernel: bool = False,
     nheads = d_in // s.head_dim
     Bsz, S, _ = u.shape
     z, x_raw, b_raw, c_raw, dt_raw = _project(p, u, cfg)
-    x = _conv_full(x_raw, p["conv_x"])
-    B = _conv_full(b_raw, p["conv_b"])
-    C = _conv_full(c_raw, p["conv_c"])
+    x = _conv_full(x_raw, p["conv_x"], p.get("conv_x_bias"))
+    B = _conv_full(b_raw, p["conv_b"], p.get("conv_b_bias"))
+    C = _conv_full(c_raw, p["conv_c"], p.get("conv_c_bias"))
     x = x.reshape(Bsz, S, nheads, s.head_dim)
     dt = jax.nn.softplus(dt_raw + p["dt_bias"].astype(dt_raw.dtype))  # (B,S,H)
     a = jnp.exp(-jnp.exp(p["a_log"].astype(jnp.float32)) * dt.astype(jnp.float32))
@@ -178,19 +189,20 @@ def mamba_fwd(p, u, cfg: ModelConfig, use_kernel: bool = False,
         ap = jnp.pad(a, ((0, 0), (0, pad), (0, 0)), constant_values=1.0)
         Bp = jnp.pad(B, ((0, 0), (0, pad), (0, 0)))
         Cp = jnp.pad(C, ((0, 0), (0, pad), (0, 0)))
-    if use_kernel:
-        from repro.kernels.ops import ssd_scan
-        y = ssd_scan(xp, dtp, ap, Bp, Cp, chunk=chunk)[:, :S]
-        h_final = None
-        if return_state:
-            _, h_final = ssd_chunked_ref(
+    with jax.named_scope("ssd"):
+        if use_kernel:
+            from repro.kernels.ops import ssd_scan
+            y = ssd_scan(xp, dtp, ap, Bp, Cp, chunk=chunk)[:, :S]
+            h_final = None
+            if return_state:
+                _, h_final = ssd_chunked_ref(
+                    xp.astype(jnp.float32), dtp.astype(jnp.float32), ap,
+                    Bp.astype(jnp.float32), Cp.astype(jnp.float32), chunk)
+        else:
+            y, h_final = ssd_chunked_ref(
                 xp.astype(jnp.float32), dtp.astype(jnp.float32), ap,
                 Bp.astype(jnp.float32), Cp.astype(jnp.float32), chunk)
-    else:
-        y, h_final = ssd_chunked_ref(
-            xp.astype(jnp.float32), dtp.astype(jnp.float32), ap,
-            Bp.astype(jnp.float32), Cp.astype(jnp.float32), chunk)
-        y = y[:, :S]
+            y = y[:, :S]
     y = y.astype(u.dtype) + x * p["d_skip"].astype(u.dtype)[None, None, :, None]
     y = y.reshape(Bsz, S, d_in)
     # gated RMSNorm (Mamba-2's out norm)
@@ -221,9 +233,12 @@ def mamba_decode(p, u, cfg: ModelConfig, state: SSMState):
     nheads = d_in // s.head_dim
     Bsz = u.shape[0]
     z, x_raw, b_raw, c_raw, dt_raw = _project(p, u, cfg)
-    x, conv_x = _conv_step(x_raw, p["conv_x"], state.conv_x)
-    B, conv_b = _conv_step(b_raw, p["conv_b"], state.conv_b)
-    C, conv_c = _conv_step(c_raw, p["conv_c"], state.conv_c)
+    x, conv_x = _conv_step(x_raw, p["conv_x"], state.conv_x,
+                           p.get("conv_x_bias"))
+    B, conv_b = _conv_step(b_raw, p["conv_b"], state.conv_b,
+                           p.get("conv_b_bias"))
+    C, conv_c = _conv_step(c_raw, p["conv_c"], state.conv_c,
+                           p.get("conv_c_bias"))
     x = x.reshape(Bsz, nheads, s.head_dim)
     B, C = B[:, 0], C[:, 0]                                    # (B, N)
     dt = jax.nn.softplus(dt_raw[:, 0] + p["dt_bias"].astype(dt_raw.dtype))

@@ -17,6 +17,15 @@ a token's top-k experts are distinct, so per-expert assignments ≤ 1 ≤ C.
 
 The auxiliary load-balance loss (Switch-style: E · Σ fraction_e · prob_e) is
 returned for the training loss.
+
+A layer told which experts it holds (``MoEConfig.held``, expert
+parallelism's local part) routes every token over all ``num_experts`` and
+computes only its held experts' gated part, **dropless**: the (token,
+expert) assignments are sorted by held expert and run through one
+``jax.lax.ragged_dot`` per projection, so each held expert computes exactly
+the tokens routed to it and nothing is padded to a capacity.  The absent
+experts' part is not computed and nothing stands in for it.  A shared
+expert (``d_shared``) is added for every token on either path.
 """
 
 from __future__ import annotations
@@ -28,18 +37,22 @@ import jax.numpy as jnp
 
 
 from repro.models.config import ModelConfig
-from repro.models.layers import truncated_normal
+from repro.models.layers import mlp_fwd, mlp_init, truncated_normal
 
 
 def moe_init(key, cfg: ModelConfig):
+    """Router over all experts; expert weights for the held ones only."""
     m = cfg.moe
-    d, ff, e = cfg.d_model, m.d_expert, m.num_experts
-    ks = jax.random.split(key, 4)
+    d, ff, e, n = cfg.d_model, m.d_expert, m.num_experts, m.num_held
+    ks = jax.random.split(key, 5)
     s_in, s_out = d ** -0.5, ff ** -0.5
-    return {"router": truncated_normal(ks[0], (d, e), s_in),
-            "wi_gate": truncated_normal(ks[1], (e, d, ff), s_in),
-            "wi_up": truncated_normal(ks[2], (e, d, ff), s_in),
-            "wo": truncated_normal(ks[3], (e, ff, d), s_out)}
+    p = {"router": truncated_normal(ks[0], (d, e), s_in),
+         "wi_gate": truncated_normal(ks[1], (n, d, ff), s_in),
+         "wi_up": truncated_normal(ks[2], (n, d, ff), s_in),
+         "wo": truncated_normal(ks[3], (n, ff, d), s_out)}
+    if m.d_shared:
+        p["shared"] = mlp_init(ks[4], cfg, d_ff=m.d_shared)
+    return p
 
 
 class MoEOut(NamedTuple):
@@ -53,11 +66,68 @@ def moe_fwd(p, x: jax.Array, cfg: ModelConfig) -> MoEOut:
     B, S, d = x.shape
     E, K = m.num_experts, m.top_k
 
-    logits = jnp.einsum("bsd,de->bse", x.astype(jnp.float32),
-                        p["router"].astype(jnp.float32))
-    probs = jax.nn.softmax(logits, axis=-1)                    # (B, S, E)
-    gate, expert_idx = jax.lax.top_k(probs, K)                 # (B, S, K)
-    gate = gate / jnp.maximum(gate.sum(-1, keepdims=True), 1e-9)
+    with jax.named_scope("router"):
+        logits = jnp.einsum("bsd,de->bse", x.astype(jnp.float32),
+                            p["router"].astype(jnp.float32))
+        probs = jax.nn.softmax(logits, axis=-1)                # (B, S, E)
+        gate, expert_idx = jax.lax.top_k(probs, K)             # (B, S, K)
+        # = the softmax over the top-k logits (granite's form)
+        gate = gate / jnp.maximum(gate.sum(-1, keepdims=True), 1e-9)
+    if m.held is not None:
+        with jax.named_scope("held_experts"):
+            y = _held_fwd(p, x, gate, expert_idx, cfg)
+    else:
+        y = _capacity_fwd(p, x, gate, expert_idx, cfg)
+    if m.d_shared:
+        with jax.named_scope("shared_expert"):
+            y = y + mlp_fwd(p["shared"], x, cfg)
+
+    # ---- Switch-style load-balance loss -------------------------------------
+    onehot = jax.nn.one_hot(expert_idx, E, dtype=jnp.float32)  # (B, S, K, E)
+    frac = onehot.sum(axis=2).mean(axis=(0, 1))                # tokens/expert
+    imp = probs.mean(axis=(0, 1))
+    aux = E * jnp.sum(frac * imp) * m.router_aux_weight
+    return MoEOut(y=y, aux_loss=aux)
+
+
+def _held_fwd(p, x, gate, expert_idx, cfg: ModelConfig):
+    """The held experts' gated part of the layer, dropless.
+
+    Assignments to held experts are sorted by expert into a buffer of
+    ``T·min(K, held)`` rows, the most that tokens' top-k can put on them,
+    and each projection is one ``ragged_dot`` over the held groups; rows
+    past the held total belong to no group and are never combined."""
+    m = cfg.moe
+    B, S, d = x.shape
+    K, (lo, n) = m.top_k, m.held
+    T = B * S
+    local = expert_idx.reshape(-1) - lo                        # (T*K,)
+    held = (local >= 0) & (local < n)
+    key = jnp.where(held, local, n)                            # absent last
+    order = jnp.argsort(key, stable=True)
+    M = T * min(K, n)
+    sizes = (key[:, None] == jnp.arange(n)).sum(0, dtype=jnp.int32)
+    xs = x.reshape(T, d)[order[:M] // K]                       # (M, d)
+
+    def grouped(a, w):      # one pass at the weights' width, as the einsums
+        return jax.lax.ragged_dot(a, w.astype(x.dtype), sizes,
+                                  precision=jax.lax.Precision.DEFAULT)
+
+    h = jax.nn.silu(grouped(xs, p["wi_gate"])) * grouped(xs, p["wi_up"])
+    out = grouped(h, p["wo"])                                  # (M, d)
+    # combine by gathering each assignment's row back (no scatter)
+    pos = jnp.argsort(order)                                   # inverse
+    rows = jnp.take(out, jnp.minimum(pos, M - 1), axis=0)     # (T*K, d)
+    w = gate.reshape(-1, 1).astype(x.dtype)
+    rows = jnp.where(held[:, None], rows * w, 0)
+    return rows.reshape(B, S, K, d).sum(axis=2)
+
+
+def _capacity_fwd(p, x, gate, expert_idx, cfg: ModelConfig):
+    """Every expert, row-local capacity dispatch (module docstring)."""
+    m = cfg.moe
+    B, S, d = x.shape
+    E, K = m.num_experts, m.top_k
 
     # ---- row-local capacity ranks ------------------------------------------
     C = int(max(1, round(S * K / E * m.capacity_factor)))
@@ -98,11 +168,5 @@ def moe_fwd(p, x: jax.Array, cfg: ModelConfig) -> MoEOut:
     # NOTE(§Perf, refuted): constraining this gather to token-major layout
     # added 40% collective bytes (GSPMD then reshards flat_out wholesale).
     w = (gate.reshape(B, S * K) * keep).astype(x.dtype)
-    y = (gathered.reshape(B, S, K, d)
-         * w.reshape(B, S, K, 1)).sum(axis=2)
-
-    # ---- Switch-style load-balance loss -------------------------------------
-    frac = onehot.astype(jnp.float32).mean(axis=(0, 1)) * K    # tokens/expert
-    imp = probs.mean(axis=(0, 1))
-    aux = E * jnp.sum(frac * imp) * m.router_aux_weight
-    return MoEOut(y=y, aux_loss=aux)
+    return (gathered.reshape(B, S, K, d)
+            * w.reshape(B, S, K, 1)).sum(axis=2)

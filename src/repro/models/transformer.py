@@ -100,36 +100,52 @@ def _layer_fwd(lp, h, cfg: ModelConfig, kind: str, *, mode: str,
     new_kv = new_ssm = None
     hn = norm_fwd(lp["norm1"], h, cfg)
     if kind == "attn":
-        if mode == "decode":
-            a, new_kv = attn.decode_attention(lp["attn"], hn, cfg, kv_cache, pos)
-        else:
-            a, kv = attn.full_attention(lp["attn"], hn, cfg, positions,
-                                        causal=True)
-            if mode == "prefill":
-                new_kv = attn.KVCache(*kv)
+        with jax.named_scope("attention"):
+            if mode == "decode":
+                a, new_kv = attn.decode_attention(lp["attn"], hn, cfg,
+                                                  kv_cache, pos)
+            else:
+                a, kv = attn.full_attention(lp["attn"], hn, cfg, positions,
+                                            causal=True)
+                if mode == "prefill":
+                    new_kv = attn.KVCache(*kv)
     else:
-        if mode == "decode":
-            a, new_ssm = mamba2.mamba_decode(lp["ssm"], hn, cfg, ssm_state)
-        else:
-            a, fin = mamba2.mamba_fwd(lp["ssm"], hn, cfg, return_state=True)
-            if mode == "prefill":
-                new_ssm = fin
+        with jax.named_scope("mamba"):
+            if mode == "decode":
+                a, new_ssm = mamba2.mamba_decode(lp["ssm"], hn, cfg,
+                                                 ssm_state)
+            else:
+                # the Pallas scan serves prefill on a TPU; training keeps
+                # the differentiable jnp scan
+                a, fin = mamba2.mamba_fwd(
+                    lp["ssm"], hn, cfg, return_state=True,
+                    use_kernel=(mode == "prefill"
+                                and jax.default_backend() == "tpu"))
+                if mode == "prefill":
+                    new_ssm = fin
 
     if cfg.parallel_block and "mlp" in lp:
         return h + a + mlp_fwd(lp["mlp"], hn, cfg), aux, new_kv, new_ssm
 
-    h = h + a
+    h = h + _residual(a, cfg)
     if cross is not None:
         cp, ckv = cross
         cn = norm_fwd(cp["norm"], h, cfg)
         h = h + attn.cross_attention(cp["attn"], cn, cfg, ckv)
     if "moe" in lp:
         out = moe_mod.moe_fwd(lp["moe"], norm_fwd(lp["norm2"], h, cfg), cfg)
-        h = h + out.y
+        h = h + _residual(out.y, cfg)
         aux = aux + out.aux_loss
     elif "mlp" in lp:
         h = h + mlp_fwd(lp["mlp"], norm_fwd(lp["norm2"], h, cfg), cfg)
     return h, aux, new_kv, new_ssm
+
+
+def _residual(branch, cfg: ModelConfig):
+    """A residual branch, scaled where the model scales it (granite)."""
+    if cfg.residual_multiplier == 1.0:
+        return branch
+    return branch * jnp.asarray(cfg.residual_multiplier, branch.dtype)
 
 
 def _stack(ts):
@@ -326,10 +342,17 @@ def _embed_inputs(params, batch, cfg: ModelConfig):
     if cfg.frontend != "none" and not cfg.is_encdec:
         fe = batch["frontend"].astype(h.dtype)       # (B, Fl, d) patch embeds
         h = jnp.concatenate([fe, h], axis=1)
-    h = constrain(h, "residual")
+    h = constrain(_scale_embeds(h, cfg), "residual")
     B, S = h.shape[0], h.shape[1]
     positions = jnp.broadcast_to(jnp.arange(S), (B, S))
     return h, positions
+
+
+def _scale_embeds(h, cfg: ModelConfig):
+    """The input embeddings, scaled where the model scales them (granite)."""
+    if cfg.embedding_multiplier == 1.0:
+        return h
+    return h * jnp.asarray(cfg.embedding_multiplier, h.dtype)
 
 
 def _taps_out(params, cfg: ModelConfig, pooled):
@@ -388,7 +411,7 @@ def prefill(params, batch, cfg: ModelConfig, max_len: int | None = None):
 
 def decode_step(params, tokens: jax.Array, caches: Caches, cfg: ModelConfig):
     """One decode step.  tokens (B, 1) -> (logits (B,1,V), Caches, taps, cls)."""
-    h = embed_fwd(params["embed"], tokens, cfg)
+    h = _scale_embeds(embed_fwd(params["embed"], tokens, cfg), cfg)
     h, aux, pooled, kv, ssm = _scan_decode(params, h, cfg, caches,
                                            caches.cross_kv)
     h = norm_fwd(params["final_norm"], h, cfg)

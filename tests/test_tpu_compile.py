@@ -211,3 +211,87 @@ def test_round_step_compiles(topo, monkeypatch, chips, I):
     table = f"f32[{L},{I},{D}]"
     assert not [ln for ln in text.splitlines()
                 if "all-gather" in ln and table in ln]
+
+
+# ---------------------------------------------------------------------------
+# granite-4.0-h-small's layer kernels at its published widths: the SSD scan
+# (128 heads of 64, d_state 128, chunk 256) and the held-expert layer (18 of
+# 72 experts of width 768, top-10, shared expert 1536), on one 8-row call of
+# 512 positions, with float32 matmuls at ``highest`` as the benchmark runs
+# them (a precision a bfloat16 kernel matmul must not inherit).
+# ---------------------------------------------------------------------------
+
+def test_ssd_scan_compiles_at_granite_widths(one_chip):
+    from repro.kernels.ssd_scan import ssd_scan
+    B_, S, H, P_, N = 8, 512, 128, 64, 128
+    with jax.default_matmul_precision("highest"):
+        text, _ = _compile(jax.jit(
+            lambda *a: ssd_scan(*a, chunk=256, interpret=False)).lower(
+            _spec((B_, S, H, P_), jnp.bfloat16, one_chip),
+            _spec((B_, S, H), jnp.float32, one_chip),
+            _spec((B_, S, H), jnp.float32, one_chip),
+            _spec((B_, S, N), jnp.bfloat16, one_chip),
+            _spec((B_, S, N), jnp.bfloat16, one_chip)))
+    assert "%ssd_scan" in text
+
+
+def test_held_expert_layer_compiles_at_granite_widths(one_chip):
+    from repro.models import moe
+    from repro.models.config import ModelConfig
+    cfg = ModelConfig(
+        name="granite-moe", family="hybrid", num_layers=1, d_model=4096,
+        num_heads=32, kv_heads=8, d_ff=0, vocab_size=100352,
+        moe={"num_experts": 72, "top_k": 10, "d_expert": 768,
+             "d_shared": 1536, "held": (0, 18)})
+    params = jax.tree.map(
+        lambda a: _spec(a.shape, jnp.bfloat16, one_chip),
+        jax.eval_shape(lambda: moe.moe_init(jax.random.PRNGKey(0), cfg)))
+    with jax.default_matmul_precision("highest"):
+        text, _ = _compile(jax.jit(
+            lambda p, x: moe.moe_fwd(p, x, cfg).y).lower(
+            params, _spec((8, 512, 4096), jnp.bfloat16, one_chip)))
+    assert text.count("%ragged-dot") >= 3
+
+
+# ---------------------------------------------------------------------------
+# Which SSD a Mamba-2 layer runs on a TPU: prefill (the serving path) runs
+# the Pallas scan; a training step keeps the differentiable jnp scan, since
+# the kernel has no backward pass.  The backend is reported as a TPU, as on
+# the chip, so the layer makes the choice it makes there.
+# ---------------------------------------------------------------------------
+
+def _mamba_cfg():
+    from repro.models.config import ModelConfig
+    return ModelConfig(
+        name="mamba-tiny", family="ssm", num_layers=2, d_model=256,
+        num_heads=4, kv_heads=4, d_ff=0, vocab_size=512,
+        ssm={"d_state": 128, "d_conv": 4, "expand": 2, "head_dim": 64,
+             "chunk": 128, "conv_bias": True})
+
+
+def _mamba_params(cfg, sharding):
+    from repro.models import init_params
+    return jax.tree.map(
+        lambda a: _spec(a.shape, a.dtype, sharding),
+        jax.eval_shape(lambda: init_params(jax.random.PRNGKey(0), cfg)))
+
+
+def test_mamba_prefill_runs_the_ssd_kernel(one_chip, monkeypatch):
+    from repro.models import prefill
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    cfg = _mamba_cfg()
+    text, _ = _compile(jax.jit(lambda p, t: prefill(p, {"tokens": t}, cfg)[0])
+                       .lower(_mamba_params(cfg, one_chip),
+                              _spec((2, 256), jnp.int32, one_chip)))
+    assert "%ssd_scan" in text
+
+
+def test_mamba_train_step_compiles_with_the_jnp_scan(one_chip, monkeypatch):
+    from repro.training.train_step import make_loss_fn
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    cfg = _mamba_cfg()
+    grad = jax.jit(jax.grad(lambda p, b: make_loss_fn(cfg)(p, b)[0]))
+    tok = _spec((2, 256), jnp.int32, one_chip)
+    text = grad.lower(_mamba_params(cfg, one_chip),
+                      {"tokens": tok, "labels": tok}).compile().as_text()
+    assert "%ssd_scan" not in text
