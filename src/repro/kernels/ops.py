@@ -21,6 +21,7 @@ from repro.kernels.cache_merge import cache_merge_round  # noqa: F401
 from repro.kernels.decode_attention import (combine_partials,  # noqa: F401
                                             decode_attention)
 from repro.kernels.flash_attention import flash_attention as _flash
+from repro.kernels.held_combine import held_combine  # noqa: F401
 from repro.kernels.ssd_scan import ssd_scan  # noqa: F401
 
 

@@ -26,6 +26,14 @@ expert) assignments are sorted by held expert and run through one
 the tokens routed to it and nothing is padded to a capacity.  The absent
 experts' part is not computed and nothing stands in for it.  A shared
 expert (``d_shared``) is added for every token on either path.
+
+The held rows come back to their tokens in one of two ways.  Prefill on a
+TPU runs the Pallas ``held_combine`` kernel, which reads only the buffer
+blocks that hold a token tile's held rows and multiplies and sums the gates
+in float32.  Training and decode keep the jnp combine, which gathers a row
+for every (token, k) assignment and zeroes the absent ones: it is
+differentiable (Pallas gives the kernel no backward pass) and it is the
+CPU path.
 """
 
 from __future__ import annotations
@@ -60,8 +68,13 @@ class MoEOut(NamedTuple):
     aux_loss: jax.Array
 
 
-def moe_fwd(p, x: jax.Array, cfg: ModelConfig) -> MoEOut:
-    """x (B, S, d) -> (B, S, d) + load-balance aux loss."""
+def moe_fwd(p, x: jax.Array, cfg: ModelConfig,
+            use_kernel: bool = False) -> MoEOut:
+    """x (B, S, d) -> (B, S, d) + load-balance aux loss.
+
+    ``use_kernel`` combines the held experts' rows with the Pallas
+    ``held_combine`` kernel, which has no backward pass; the default is
+    the differentiable jnp combine."""
     m = cfg.moe
     B, S, d = x.shape
     E, K = m.num_experts, m.top_k
@@ -75,7 +88,7 @@ def moe_fwd(p, x: jax.Array, cfg: ModelConfig) -> MoEOut:
         gate = gate / jnp.maximum(gate.sum(-1, keepdims=True), 1e-9)
     if m.held is not None:
         with jax.named_scope("held_experts"):
-            y = _held_fwd(p, x, gate, expert_idx, cfg)
+            y = _held_fwd(p, x, gate, expert_idx, cfg, use_kernel)
     else:
         y = _capacity_fwd(p, x, gate, expert_idx, cfg)
     if m.d_shared:
@@ -90,7 +103,7 @@ def moe_fwd(p, x: jax.Array, cfg: ModelConfig) -> MoEOut:
     return MoEOut(y=y, aux_loss=aux)
 
 
-def _held_fwd(p, x, gate, expert_idx, cfg: ModelConfig):
+def _held_fwd(p, x, gate, expert_idx, cfg: ModelConfig, use_kernel: bool):
     """The held experts' gated part of the layer, dropless.
 
     Assignments to held experts are sorted by expert into a buffer of
@@ -115,12 +128,24 @@ def _held_fwd(p, x, gate, expert_idx, cfg: ModelConfig):
 
     h = jax.nn.silu(grouped(xs, p["wi_gate"])) * grouped(xs, p["wi_up"])
     out = grouped(h, p["wo"])                                  # (M, d)
-    # combine by gathering each assignment's row back (no scatter)
-    pos = jnp.argsort(order)                                   # inverse
-    rows = jnp.take(out, jnp.minimum(pos, M - 1), axis=0)     # (T*K, d)
-    w = gate.reshape(-1, 1).astype(x.dtype)
-    rows = jnp.where(held[:, None], rows * w, 0)
-    return rows.reshape(B, S, K, d).sum(axis=2)
+    pos = jnp.argsort(order).reshape(T, K)        # each assignment's row
+    gate = gate.reshape(T, K)
+    if use_kernel:
+        from repro.kernels.ops import held_combine
+        y = held_combine(out, pos, gate, sizes)
+    else:
+        y = _gather_combine(out, pos, gate, held.reshape(T, K))
+    return y.reshape(B, S, d)
+
+
+def _gather_combine(out, pos, gate, held):
+    """(T, K) assignments -> (T, d): each assignment's row gathered back
+    (no scatter), weighted by its gate in ``out``'s dtype, absent ones
+    zeroed, summed over k."""
+    rows = jnp.take(out, jnp.minimum(pos, out.shape[0] - 1), axis=0)
+    rows = jnp.where(held[..., None], rows * gate[..., None].astype(out.dtype),
+                     0)
+    return rows.sum(axis=1)
 
 
 def _capacity_fwd(p, x, gate, expert_idx, cfg: ModelConfig):

@@ -133,7 +133,11 @@ def _layer_fwd(lp, h, cfg: ModelConfig, kind: str, *, mode: str,
         cn = norm_fwd(cp["norm"], h, cfg)
         h = h + attn.cross_attention(cp["attn"], cn, cfg, ckv)
     if "moe" in lp:
-        out = moe_mod.moe_fwd(lp["moe"], norm_fwd(lp["norm2"], h, cfg), cfg)
+        # prefill on a TPU combines the held experts with the Pallas
+        # kernel; training and decode keep the differentiable jnp combine
+        out = moe_mod.moe_fwd(
+            lp["moe"], norm_fwd(lp["norm2"], h, cfg), cfg,
+            use_kernel=(mode == "prefill" and jax.default_backend() == "tpu"))
         h = h + _residual(out.y, cfg)
         aux = aux + out.aux_loss
     elif "mlp" in lp:

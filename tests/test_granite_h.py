@@ -148,10 +148,12 @@ def _held(p, lo, n):
                                                    "wo")})
 
 
-def test_quarters_add_up_to_the_uncut_layer():
+@pytest.mark.parametrize("use_kernel", [False, True])
+def test_quarters_add_up_to_the_uncut_layer(use_kernel):
     """The share test: four chips' held-expert parts, with the shared
     expert (which every chip computes alike) counted once, add up to the
-    uncut reference layer.  Tolerance: float32 sums in another order."""
+    uncut reference layer, with either combine.  Tolerance: float32 sums in
+    another order."""
     m = _cfg()["model"]
     p = _full_moe_params(jax.random.PRNGKey(5), m)
     x = jax.random.normal(jax.random.PRNGKey(6), (2, 20, m["d_model"]))
@@ -161,17 +163,19 @@ def test_quarters_add_up_to_the_uncut_layer():
     total = -3 * shared
     for q in range(4):
         mc = ModelConfig(**_cfg(moe={"held": [2 * q, 2]})["model"])
-        total = total + moe.moe_fwd(_held(p, 2 * q, 2), x, mc).y
+        total = total + moe.moe_fwd(_held(p, 2 * q, 2), x, mc,
+                                    use_kernel=use_kernel).y
     uncut = granite_h.experts(p, x, _cfg(moe={"held": [0, 8]})["model"],
                               "highest")
     np.testing.assert_allclose(np.asarray(total), np.asarray(uncut),
                                rtol=1e-4, atol=1e-5)
 
 
-def test_dropless_when_one_expert_takes_every_token():
-    """Every assignment to a held expert is computed, even when one held
-    expert is chosen by every token (a capacity of 1.25 x the mean would
-    drop most of them)."""
+@pytest.mark.parametrize("use_kernel", [False, True])
+def test_dropless_when_one_expert_takes_every_token(use_kernel):
+    """Every assignment to a held expert is computed and combined, even
+    when one held expert is chosen by every token (a capacity of 1.25 x the
+    mean would drop most of them)."""
     m = _cfg()["model"]
     p = _full_moe_params(jax.random.PRNGKey(8), m)
     p["router"] = p["router"].at[:, 0].set(10.0)     # expert 0 always wins
@@ -180,7 +184,8 @@ def test_dropless_when_one_expert_takes_every_token():
     idx = jax.lax.top_k(x @ p["router"], 3)[1]
     assert bool((idx == 0).any(-1).all())
     held = _cfg(moe={"held": [0, 2]})["model"]
-    got = moe.moe_fwd(_held(p, 0, 2), x, ModelConfig(**held)).y
+    got = moe.moe_fwd(_held(p, 0, 2), x, ModelConfig(**held),
+                      use_kernel=use_kernel).y
     want = granite_h.experts(_held(p, 0, 2), x, held, "highest")
     np.testing.assert_allclose(np.asarray(got), np.asarray(want),
                                rtol=1e-4, atol=1e-5)

@@ -419,6 +419,60 @@ def test_ssd_scan_state_continuity():
                                rtol=1e-4, atol=1e-4)
 
 
+# ---------------------------------------------------------------------------
+# held-expert combine
+# ---------------------------------------------------------------------------
+
+def _held_case(T, K, E, n, route, d, dtype, seed):
+    """The held-expert layer's buffer as ``moe._held_fwd`` lays it out, with
+    every row at or past the held total poisoned with NaN.  Token 0 picks
+    K held experts (when n >= K), token 1 none."""
+    from repro.models import moe
+    scores = jax.random.normal(k(seed), (T, E))
+    if route == "one_expert":                      # expert 0 in every top-k
+        scores = scores.at[:, 0].set(1e3)
+    gate, idx = jax.lax.top_k(jax.nn.softmax(scores), K)
+    if n >= K:
+        idx = idx.at[0].set(jnp.arange(K))
+    idx = idx.at[1].set(jnp.arange(E - K, E))
+    held = idx < n
+    key = jnp.where(held, idx, n).reshape(-1)
+    order = jnp.argsort(key, stable=True)
+    sizes = (key[:, None] == jnp.arange(n)).sum(0, dtype=jnp.int32)
+    H, M = int(sizes.sum()), T * min(K, n)
+    out = jax.random.normal(k(seed + 1), (M, d)).astype(dtype)
+    out = out.at[H:].set(jnp.nan)
+    pos = jnp.argsort(order).reshape(T, K)
+    want = moe._gather_combine(out.astype(jnp.float32), pos, gate, held)
+    return out, pos, gate, sizes, held, want
+
+
+@pytest.mark.parametrize("T,K,E,n,route,tile", [
+    (20, 3, 8, 4, "random", 8),          # T not a multiple of the tile
+    (37, 3, 8, 4, "one_expert", 16),     # one expert takes every token
+    (64, 4, 16, 2, "random", 16),        # fewer held experts than top-k
+])
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+def test_held_combine_matches_the_jnp_combine(T, K, E, n, route, tile,
+                                              dtype):
+    """The kernel equals the float32 jnp combine of the same rows, and
+    every row at or past the held total is NaN, so a finite, equal result
+    shows that no such row reaches the sum."""
+    out, pos, gate, sizes, held, want = _held_case(T, K, E, n, route, 128,
+                                                   dtype, 50 + T)
+    got = np.asarray(ops.held_combine(out, pos, gate, sizes, tile=tile),
+                     np.float32)
+    assert got.dtype == np.float32 and np.isfinite(got).all()
+    if n >= K:
+        assert bool(held[0].all())
+    assert not bool(held[1].any()) and not got[1].any()
+    if route == "one_expert":                      # all but token 1
+        assert int(sizes[0]) == T - 1
+    # float32: products and sums in float32; bfloat16: one rounding
+    tol = 1e-6 if dtype == jnp.float32 else 2.0 ** -8
+    np.testing.assert_allclose(got, np.asarray(want), rtol=tol, atol=1e-6)
+
+
 @pytest.mark.parametrize("impl", ["fused_single", "fused_tiled"])
 @pytest.mark.parametrize("quantized", [False, True])
 def test_stacked_tables_lookup_matches_per_table(impl, quantized):

@@ -235,22 +235,49 @@ def test_ssd_scan_compiles_at_granite_widths(one_chip):
     assert "%ssd_scan" in text
 
 
-def test_held_expert_layer_compiles_at_granite_widths(one_chip):
-    from repro.models import moe
+def _granite_moe_cfg():
     from repro.models.config import ModelConfig
-    cfg = ModelConfig(
+    return ModelConfig(
         name="granite-moe", family="hybrid", num_layers=1, d_model=4096,
         num_heads=32, kv_heads=8, d_ff=0, vocab_size=100352,
-        moe={"num_experts": 72, "top_k": 10, "d_expert": 768,
-             "d_shared": 1536, "held": (0, 18)})
+        position="nope", moe={"num_experts": 72, "top_k": 10,
+                              "d_expert": 768, "d_shared": 1536,
+                              "held": (0, 18)})
+
+
+@pytest.mark.parametrize("mode", ["prefill", "train"])
+def test_held_expert_layer_compiles_at_granite_widths(one_chip, monkeypatch,
+                                                      mode):
+    """A held MoE layer on a TPU: prefill (one 8-row call of 512
+    positions, T·K = 40,960 assignments) combines with the Pallas
+    ``held_combine`` kernel and keeps one (T·K, d) gather, the dispatch's;
+    a training step's gradient keeps the jnp combine, since the kernel has
+    no backward pass.  Both run the grouped matmuls as ragged dots."""
+    import re
+    from repro.models import init_params, prefill
+    from repro.training.train_step import make_loss_fn
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    cfg = _granite_moe_cfg()
     params = jax.tree.map(
         lambda a: _spec(a.shape, jnp.bfloat16, one_chip),
-        jax.eval_shape(lambda: moe.moe_init(jax.random.PRNGKey(0), cfg)))
+        jax.eval_shape(lambda: init_params(jax.random.PRNGKey(0), cfg)))
     with jax.default_matmul_precision("highest"):
-        text, _ = _compile(jax.jit(
-            lambda p, x: moe.moe_fwd(p, x, cfg).y).lower(
-            params, _spec((8, 512, 4096), jnp.bfloat16, one_chip)))
+        if mode == "prefill":
+            text, _ = _compile(jax.jit(
+                lambda p, t: prefill(p, {"tokens": t}, cfg)[0]).lower(
+                params, _spec((8, 512), jnp.int32, one_chip)))
+        else:
+            tok = _spec((2, 256), jnp.int32, one_chip)
+            text = jax.jit(jax.grad(
+                lambda p, b: make_loss_fn(cfg)(p, b)[0])).lower(
+                params, {"tokens": tok, "labels": tok}).compile().as_text()
     assert text.count("%ragged-dot") >= 3
+    if mode == "prefill":
+        assert "%held_combine" in text
+        assert len(re.findall(r"= bf16\[40960,4096\]\S* gather\(",
+                              text)) == 1
+    else:
+        assert "%held_combine" not in text
 
 
 # ---------------------------------------------------------------------------
